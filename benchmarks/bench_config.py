@@ -88,7 +88,7 @@ def label_entry(entry: dict, **overrides) -> dict:
 
 def launch_env() -> dict:
     """The launcher-pinned environment, dumped into each bench JSON so a
-    committed number carries its own repro recipe (SNIPPETS.md idiom)."""
+    committed number carries its own repro recipe."""
     keys = ("XLA_FLAGS", "LD_PRELOAD", "JAX_DEFAULT_DTYPE_BITS",
             "TF_CPP_MIN_LOG_LEVEL", "BENCH_BACKEND", "BENCH_COMPILED")
     env = {k: os.environ[k] for k in keys if k in os.environ}

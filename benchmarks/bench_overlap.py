@@ -24,19 +24,17 @@ import jax, jax.numpy as jnp
 from repro.core import LatticeShape, pack_gauge, pack_spinor
 from repro.core import distributed as dist
 from repro.data import lattice_problem
-
-from repro.compat import make_mesh, shard_map
-mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
 lat = LatticeShape(8, 8, 8, 8)
 up, pp = lattice_problem(lat, mass=0.1)
 upd, ppd = dist.shard_lattice_fields(mesh, up, pp)
 psi_spec, gauge_spec, sharded = dist.lattice_specs(mesh)
 
-halo = jax.jit(shard_map(lambda u, p: dist.dslash_halo(u, p, 0.1, sharded),
+halo = jax.jit(jax.shard_map(lambda u, p: dist.dslash_halo(u, p, 0.1, sharded),
                          mesh=mesh, in_specs=(gauge_spec, psi_spec),
                          out_specs=psi_spec))
 from repro.core.wilson import dslash_packed
-bulk = jax.jit(shard_map(lambda u, p: dslash_packed(u, p, 0.1),
+bulk = jax.jit(jax.shard_map(lambda u, p: dslash_packed(u, p, 0.1),
                          mesh=mesh, in_specs=(gauge_spec, psi_spec),
                          out_specs=psi_spec))
 

@@ -107,9 +107,7 @@ from repro.core import LatticeShape
 from repro.core import distributed as dist
 from repro.data import lattice_problem
 from repro.core.wilson import dslash_packed
-
-from repro.compat import make_mesh
-mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
 lat = LatticeShape(4, 4, 4, 8)
 up, pp = lattice_problem(lat, mass=0.3)
 upd, ppd = dist.shard_lattice_fields(mesh, up, pp)
@@ -475,12 +473,11 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json, time
 import jax, jax.numpy as jnp
-from repro.compat import make_mesh
 from repro.core import LatticeShape, random_gauge, random_spinor
 from repro.core import plan as plan_mod
 from repro.core.wilson import dslash
 
-mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
 lat = LatticeShape(%(t)d, %(z)d, %(y)d, 8)
 mass, tol, seed, n = %(mass)r, %(tol)r, %(seed)d, %(n)d
 ku, kb = jax.random.split(jax.random.PRNGKey(seed))
@@ -497,7 +494,8 @@ t0 = time.time()
 x, st = plan_mod.solve(p, u, b, mass, tol=tol, maxiter=500)
 jax.block_until_ready(x)
 us = (time.time() - t0) * 1e6
-res = jax.vmap(lambda xx, bb: dslash(u, xx, mass) - bb)(x, b)
+# gather to the host: dslash is the global-view operator
+res = jax.vmap(lambda xx, bb: dslash(u, xx, mass) - bb)(jax.device_get(x), b)
 rel = float(jnp.max(jnp.linalg.norm(res.reshape(n, -1), axis=1)
                     / jnp.linalg.norm(b.reshape(n, -1), axis=1)))
 out = {"lattice": str(lat), "mass": mass, "tol": tol, "seed": seed,

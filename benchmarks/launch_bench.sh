@@ -1,10 +1,10 @@
 #!/usr/bin/env bash
 # Reproducible compiled-backend bench launcher (DESIGN.md §13).
 #
-# Pins the XLA launch environment (the SNIPPETS.md / HomebrewNLP run.sh
-# idiom) so committed numbers carry a repro recipe: host device count,
-# tcmalloc preload, f32 dtype pinning, quiet logs.  The pinned env is
-# dumped into every bench JSON by bench_config.launch_env().
+# Pins the XLA launch environment so committed numbers carry a repro
+# recipe: host device count, tcmalloc preload, f32 dtype pinning, quiet
+# logs.  The pinned env is dumped into every bench JSON by
+# bench_config.launch_env().
 #
 # Usage:
 #   benchmarks/launch_bench.sh                    # dslash + solvers, CPU

@@ -55,6 +55,8 @@ def main(argv=None) -> int:
     # configure BEFORE the bench modules import jax and read the mode
     from benchmarks import bench_config
     bench_config.configure(backend=args.backend, compiled=args.compiled)
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from benchmarks import (bench_dslash, bench_mixed_precision,
                             bench_overlap, bench_solvers, roofline)

@@ -18,8 +18,6 @@ if "xla_force_host_platform_device_count" not in \
 
 import jax                                                      # noqa: E402
 import jax.numpy as jnp                                         # noqa: E402
-
-from repro.compat import make_mesh                              # noqa: E402
 from repro.core import LatticeShape                             # noqa: E402
 from repro.core import distributed as dist                      # noqa: E402
 from repro.core.wilson import dslash_packed                     # noqa: E402
@@ -27,7 +25,7 @@ from repro.data import lattice_problem                          # noqa: E402
 
 
 def main():
-    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
     print(f"[dist] devices={len(jax.devices())} mesh={dict(mesh.shape)}")
 
     lat = LatticeShape(8, 8, 8, 8)

@@ -56,7 +56,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core import distributed as dist
 from repro.core import solvers
 from repro.core.eo import (EOContext, back_substitute_odd, eo_context,
@@ -258,6 +257,34 @@ def resolve(plan: SolverPlan, u: Array, mass, *,
 VERIFY_FACTOR = 10.0
 
 
+def _true_residual_mesh(plan: SolverPlan, u: Array, b: Array, mass,
+                        x: Array, layout: str) -> tuple[Array, Array]:
+    """(‖b - D x‖², ‖b‖²) of a mesh plan, computed inside ``shard_map``.
+
+    The operator is the full-lattice halo dslash with the jnp bulk stencil
+    (:func:`repro.core.distributed.dslash_halo`): each device applies D to
+    its own shard and exchanges boundary planes, and the two norms are
+    psum-reduced — the global-view operator never sees a sharded field.
+    """
+    mesh = plan.mesh
+    psi_spec, gauge_spec, sharded = dist.lattice_specs(mesh, plan.axis_map)
+    bspec = P(None, *psi_spec) if plan.batched else psi_spec
+    twist = _family_site(plan, mass).twist
+    _, pnorm2 = dist.make_psum_dots(mesh, batched=plan.batched)
+    if layout == "natural":
+        u, b, x = pack_gauge(u), pack_spinor(b), pack_spinor(x)
+
+    def local(u_l, b_l, x_l):
+        apply_d = lambda v: dist.dslash_halo(u_l, v, mass, sharded, r=plan.r,
+                                             twist=twist)
+        ax = jax.vmap(apply_d)(x_l) if plan.batched else apply_d(x_l)
+        return pnorm2(b_l - ax.astype(b_l.dtype)), pnorm2(b_l)
+
+    return jax.jit(jax.shard_map(
+        local, mesh=mesh, in_specs=(gauge_spec, bspec, bspec),
+        out_specs=(P(), P()), check_vma=False))(u, b, x)
+
+
 def _attach_verification(plan: SolverPlan, u: Array, b: Array, mass,
                          x: Array, stats: solvers.SolveStats, tol,
                          layout: str = "natural") -> solvers.SolveStats:
@@ -265,7 +292,8 @@ def _attach_verification(plan: SolverPlan, u: Array, b: Array, mass,
 
     The oracle is the operator REGISTRY's natural-layout ``dslash_g``
     (packed solves verify through the packed transport's ``dslash``, the
-    same operator on the wire format) — deliberately independent of the
+    same operator on the wire format; mesh plans through the jnp halo
+    dslash inside ``shard_map``) — deliberately independent of the
     Schur/normal-equation transforms the solver iterated on, so a broken
     transport cannot vouch for itself.  Fills ``true_residual_norm2`` and
     ``verified`` on the stats and upgrades the verdict to NONFINITE when
@@ -274,20 +302,23 @@ def _attach_verification(plan: SolverPlan, u: Array, b: Array, mass,
     application after the iteration loop.
     """
     site = _family_site(plan, mass)
-    if layout == "packed":
-        # u/b/x are packed real fields here; wops.dslash takes a leading
-        # RHS-batch axis natively
-        from repro.kernels.wilson_dslash import ops as wops
-        ax = wops.dslash(u, x, float(mass), twist=site.twist, bz=plan.bz,
-                         interpret=plan.interpret,
-                         use_pallas=plan.backend == "pallas")
+    if plan.mesh is not None:
+        rs_true, bs = _true_residual_mesh(plan, u, b, mass, x, layout)
     else:
-        apply_d = lambda v: dslash_g(u, v, mass, r=plan.r, twist=site.twist)
-        ax = jax.vmap(apply_d)(x) if plan.batched else apply_d(x)
-    r_true = b - ax.astype(b.dtype)
-    norm2_fn = field_norm2_batched if plan.batched else field_norm2
-    rs_true = jnp.real(norm2_fn(r_true))
-    bs = jnp.real(norm2_fn(b))
+        if layout == "packed":
+            # u/b/x are packed real fields here; wops.dslash takes a
+            # leading RHS-batch axis natively
+            from repro.kernels.wilson_dslash import ops as wops
+            ax = wops.dslash(u, x, float(mass), twist=site.twist,
+                             bz=plan.bz, interpret=plan.interpret,
+                             use_pallas=plan.backend == "pallas")
+        else:
+            apply_d = lambda v: dslash_g(u, v, mass, r=plan.r,
+                                         twist=site.twist)
+            ax = jax.vmap(apply_d)(x) if plan.batched else apply_d(x)
+        norm2_fn = field_norm2_batched if plan.batched else field_norm2
+        rs_true = jnp.real(norm2_fn(b - ax.astype(b.dtype)))
+        bs = jnp.real(norm2_fn(b))
     tol_a = jnp.asarray(tol).astype(rs_true.dtype)
     gate = (VERIFY_FACTOR * tol_a) ** 2 * bs
     finite = jnp.isfinite(rs_true)
@@ -701,7 +732,7 @@ def _solve_full_sharded(plan, u, b, mass, *, tol, maxiter, inner_tol,
 
     stats_spec = solvers.SolveStats(P(), P(), P(), P(), None, verdict=P(),
                                     matvecs=P())
-    shmapped = compat.shard_map(
+    shmapped = jax.shard_map(
         local_solve, mesh=mesh,
         in_specs=(gauge_spec, psi_spec),
         out_specs=(psi_spec, stats_spec),
@@ -830,7 +861,7 @@ def _sharded_eo_solver(plan: SolverPlan, mass: float, tol: float,
     stats_spec = solvers.SolveStats(P(), P(), P(), P(),
                                     P() if batched else None,
                                     verdict=P(), matvecs=P())
-    solver = jax.jit(compat.shard_map(
+    solver = jax.jit(jax.shard_map(
         local_solve, mesh=mesh,
         in_specs=(gauge_spec, gauge_spec, bspec, bspec),
         out_specs=(bspec, bspec, stats_spec),
@@ -1170,13 +1201,13 @@ def _sharded_eo_segment_fns(plan: SolverPlan, mass: float, tol: float,
         x_o = m_inv(pbo_l - d_oe(x_e))
         return x_e, x_o, stats
 
-    start = jax.jit(compat.shard_map(
+    start = jax.jit(jax.shard_map(
         local_start, mesh=mesh, in_specs=gspecs,
         out_specs=(carry_spec, P()), check_vma=False))
-    step = jax.jit(compat.shard_map(
+    step = jax.jit(jax.shard_map(
         local_step, mesh=mesh, in_specs=gspecs + (carry_spec, P()),
         out_specs=(carry_spec, P()), check_vma=False))
-    finish = jax.jit(compat.shard_map(
+    finish = jax.jit(jax.shard_map(
         local_finish, mesh=mesh, in_specs=gspecs + (carry_spec,),
         out_specs=(bspec, bspec, stats_spec), check_vma=False))
     fns = (start, step, finish)

@@ -49,7 +49,8 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.lattice import (field_dot, field_dot_batched, field_norm2,
+from repro.core.lattice import (exact_einsum, exact_matmul, field_dot,
+                                field_dot_batched, field_norm2,
                                 field_norm2_batched)
 
 Array = jax.Array
@@ -892,15 +893,15 @@ def gram(a: Array, b: Array) -> Array:
     natural fields."""
     a2 = a.reshape(a.shape[0], -1)
     b2 = b.reshape(b.shape[0], -1)
-    return jnp.einsum("if,jf->ij", a2.conj(), b2)
+    return exact_einsum("if,jf->ij", a2.conj(), b2)
 
 
 def _mix(fields: Array, coef: Array) -> Array:
     """Column mixing ``out_j = Σ_i fields_i · coef[i, j]`` over the leading
     RHS axis — the block-CG generalization of ``alpha * p``."""
     f2 = fields.reshape(fields.shape[0], -1)
-    return jnp.einsum("ij,if->jf", coef.astype(f2.dtype),
-                      f2).reshape(fields.shape)
+    return exact_einsum("ij,if->jf", coef.astype(f2.dtype),
+                        f2).reshape(fields.shape)
 
 
 def _gram_psolve(g: Array, rhs: Array, rcond: float = 1e-7) -> Array:
@@ -913,8 +914,8 @@ def _gram_psolve(g: Array, rhs: Array, rcond: float = 1e-7) -> Array:
     cut = rcond * jnp.maximum(jnp.max(jnp.abs(evals)), 1e-30)
     inv = jnp.where(evals > cut, 1.0 / jnp.where(evals > cut, evals, 1.0),
                     0.0)
-    return evecs @ (inv[:, None].astype(rhs.dtype)
-                    * (evecs.conj().T @ rhs))
+    return exact_matmul(evecs, inv[:, None].astype(rhs.dtype)
+                        * exact_matmul(evecs.conj().T, rhs))
 
 
 def blockcg(op: Op, b: Array, x0: Array | None = None, *,
@@ -1140,7 +1141,7 @@ def ritz_deflation_basis(op: Op, v: Array, alphas: Array, betas: Array,
     signs = (-1.0) ** np.arange(m)
     yk = jnp.asarray((y[:, :n_eff] * signs[:, None]).astype(np.float32))
     vm = v[:m]
-    w = jnp.einsum("km,k...->m...", yk.astype(vm.dtype), vm)
+    w = exact_einsum("km,k...->m...", yk.astype(vm.dtype), vm)
     aw = jnp.stack([op(w[i]) for i in range(n_eff)])
     g = gram(w, aw)
     if n_eff < nev:
@@ -1163,7 +1164,7 @@ def deflate_x0(basis: DeflationBasis, rhs: Array) -> Array:
     w2 = basis.w.reshape(nev, -1)
     batched = rhs.ndim == basis.w.ndim
     r2 = rhs.reshape(rhs.shape[0] if batched else 1, -1)
-    proj = jnp.einsum("kf,nf->kn", w2.conj(), r2)
+    proj = exact_einsum("kf,nf->kn", w2.conj(), r2)
     c = jnp.linalg.solve(basis.gram, proj)
-    x0 = jnp.einsum("kn,kf->nf", c, w2.astype(c.dtype))
+    x0 = exact_einsum("kn,kf->nf", c, w2.astype(c.dtype))
     return x0.reshape(rhs.shape).astype(rhs.dtype)

@@ -63,7 +63,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.lattice import NCOL, NDIRS, NSPIN, eo_row_offset
+from repro.core.lattice import (NCOL, NDIRS, NSPIN, eo_row_offset,
+                                 exact_einsum)
 
 # ---------------------------------------------------------------------------
 # Gamma matrices, DeGrand-Rossi basis, order (t, z, y, x) = axes (0,1,2,3)
@@ -125,13 +126,13 @@ def dslash(u: jax.Array, psi: jax.Array, mass: float | jax.Array,
         umu = u[mu]
         # forward hop: (r - gamma_mu) U_mu(x) psi(x + mu)
         fwd = jnp.roll(psi, -1, axis=mu)
-        hf = jnp.einsum("tzyxab,tzyxsb->tzyxsa", umu, fwd)
-        hf = jnp.einsum("sp,tzyxpa->tzyxsa", pm[mu], hf)
+        hf = exact_einsum("tzyxab,tzyxsb->tzyxsa", umu, fwd)
+        hf = exact_einsum("sp,tzyxpa->tzyxsa", pm[mu], hf)
         # backward hop: (r + gamma_mu) U_mu(x - mu)^dag psi(x - mu)
         bwd = jnp.roll(psi, 1, axis=mu)
         ubw = jnp.roll(umu, 1, axis=mu)
-        hb = jnp.einsum("tzyxba,tzyxsb->tzyxsa", jnp.conj(ubw), bwd)
-        hb = jnp.einsum("sp,tzyxpa->tzyxsa", pp[mu], hb)
+        hb = exact_einsum("tzyxba,tzyxsb->tzyxsa", jnp.conj(ubw), bwd)
+        hb = exact_einsum("sp,tzyxpa->tzyxsa", pp[mu], hb)
         out = out - 0.5 * (hf + hb)
     return out
 
@@ -202,10 +203,10 @@ def _hop_half(u_out: jax.Array, u_nbr: jax.Array, psi: jax.Array,
             u_fwd = u_out[3]
             bwd = jnp.where(sel_s, psi, jnp.roll(psi, 1, axis=3))
             u_bwd = jnp.where(sel_g, u_nbr[3], jnp.roll(u_nbr[3], 1, axis=3))
-        hf = jnp.einsum("tzyjab,tzyjsb->tzyjsa", u_fwd, fwd)
-        hf = jnp.einsum("sp,tzyjpa->tzyjsa", pm[mu], hf)
-        hb = jnp.einsum("tzyjba,tzyjsb->tzyjsa", jnp.conj(u_bwd), bwd)
-        hb = jnp.einsum("sp,tzyjpa->tzyjsa", pp[mu], hb)
+        hf = exact_einsum("tzyjab,tzyjsb->tzyjsa", u_fwd, fwd)
+        hf = exact_einsum("sp,tzyjpa->tzyjsa", pm[mu], hf)
+        hb = exact_einsum("tzyjba,tzyjsb->tzyjsa", jnp.conj(u_bwd), bwd)
+        hb = exact_einsum("sp,tzyjpa->tzyjsa", pp[mu], hb)
         out = out - 0.5 * (hf + hb)
     return out
 
@@ -316,7 +317,7 @@ def hop_term_packed(u_mu: jax.Array, psi_nbr: jax.Array, mu: int,
     ur, ui = g[..., 0, :], g[..., 1, :]
 
     sub = "tzyabx,tzysbx->tzysax" if forward else "tzybax,tzysbx->tzysax"
-    e = partial(jnp.einsum, sub, preferred_element_type=acc)
+    e = partial(exact_einsum, sub, preferred_element_type=acc)
     if forward:
         hr, hi = e(ur, pr) - e(ui, pi), e(ur, pi) + e(ui, pr)
     else:  # U^dag
@@ -324,7 +325,7 @@ def hop_term_packed(u_mu: jax.Array, psi_nbr: jax.Array, mu: int,
 
     mr = jnp.asarray(np.real(P), dtype=hr.dtype)
     mi = jnp.asarray(np.imag(P), dtype=hr.dtype)
-    es = partial(jnp.einsum, "sp,tzypcx->tzyscx", preferred_element_type=acc)
+    es = partial(exact_einsum, "sp,tzypcx->tzyscx", preferred_element_type=acc)
     outr, outi = es(mr, hr) - es(mi, hi), es(mr, hi) + es(mi, hr)
     out = jnp.stack([outr, outi], axis=5).reshape(t, z, y, s, x)
     return (-0.5 * out).astype(psi_nbr.dtype)
@@ -358,7 +359,7 @@ def dslash_packed(up: jax.Array, pp: jax.Array, mass,
     def cdot_color(ar, ai, br, bi, dag: bool):
         """(U or U^dag) @ psi over color: a=(...,3,3,X), b=(...,4,3,X)."""
         sub = "tzyabx,tzysbx->tzysax" if not dag else "tzybax,tzysbx->tzysax"
-        e = partial(jnp.einsum, sub, preferred_element_type=acc)
+        e = partial(exact_einsum, sub, preferred_element_type=acc)
         if not dag:
             return e(ar, br) - e(ai, bi), e(ar, bi) + e(ai, br)
         return e(ar, br) + e(ai, bi), e(ar, bi) - e(ai, br)
@@ -367,7 +368,7 @@ def dslash_packed(up: jax.Array, pp: jax.Array, mass,
         """4x4 complex constant acting on the spin axis (3)."""
         mr = jnp.asarray(np.real(mat), dtype=hr.dtype)
         mi = jnp.asarray(np.imag(mat), dtype=hr.dtype)
-        e = partial(jnp.einsum, "sp,tzypcx->tzyscx", preferred_element_type=acc)
+        e = partial(exact_einsum, "sp,tzypcx->tzyscx", preferred_element_type=acc)
         return e(mr, hr) - e(mi, hi), e(mr, hi) + e(mi, hr)
 
     for mu in range(NDIRS):

@@ -38,7 +38,7 @@ def _update_kernel(alpha_ref, x_ref, r_ref, p_ref, ap_ref,
     r = r_ref[...].astype(jnp.float32) - alpha * ap
     xo_ref[...] = x.astype(xo_ref.dtype)
     ro_ref[...] = r.astype(ro_ref.dtype)
-    rs_ref[0, 0] = jnp.sum(r * r)
+    rs_ref[...] = jnp.full(rs_ref.shape, jnp.sum(r * r), jnp.float32)
 
 
 def cg_update_pallas(alpha: jax.Array, x: jax.Array, r: jax.Array,
@@ -54,17 +54,19 @@ def cg_update_pallas(alpha: jax.Array, x: jax.Array, r: jax.Array,
     nb = rows // block_rows
     vec = pl.BlockSpec((block_rows, LANE), lambda i: (i, 0))
     scal = pl.BlockSpec((1, 1), lambda i: (0, 0))
+    # each block's partial sum fills one lane-wide row: Mosaic needs a
+    # block's trailing two dims (8,128)-aligned or equal to the array's
     xo, ro, rs = pl.pallas_call(
         _update_kernel,
         grid=(nb,),
         in_specs=[scal, vec, vec, vec, vec],
-        out_specs=[vec, vec, pl.BlockSpec((1, 1), lambda i: (i, 0))],
+        out_specs=[vec, vec, pl.BlockSpec((1, 1, LANE), lambda i: (i, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
                    jax.ShapeDtypeStruct(r.shape, r.dtype),
-                   jax.ShapeDtypeStruct((nb, 1), jnp.float32)],
+                   jax.ShapeDtypeStruct((nb, 1, LANE), jnp.float32)],
         interpret=resolve_interpret(interpret),
     )(jnp.asarray(alpha, jnp.float32).reshape(1, 1), x, r, p, ap)
-    return xo, ro, jnp.sum(rs)
+    return xo, ro, jnp.sum(rs[:, 0, 0])
 
 
 def _xpay_kernel(beta_ref, r_ref, p_ref, po_ref):
@@ -94,21 +96,21 @@ def cg_xpay_pallas(beta: jax.Array, r: jax.Array, p: jax.Array, *,
 # ---------------------------------------------------------------------------
 # Multi-RHS (batched) variants: vectors are (N, rows, 128), scalars are
 # per-RHS (N,).  The grid gains a leading batch dimension; per-RHS partial
-# sums land in an (N, nb) output so each right-hand side keeps its own
+# sums land in an (N, nb, 1, 128) output so each right-hand side keeps its own
 # residual norm — the solver's convergence mask needs them separately.
 # ---------------------------------------------------------------------------
 
 
 def _update_batched_kernel(alpha_ref, x_ref, r_ref, p_ref, ap_ref,
                            xo_ref, ro_ref, rs_ref):
-    alpha = alpha_ref[0, 0]
+    alpha = alpha_ref[0, 0, 0]
     p = p_ref[...].astype(jnp.float32)
     ap = ap_ref[...].astype(jnp.float32)
     x = x_ref[...].astype(jnp.float32) + alpha * p
     r = r_ref[...].astype(jnp.float32) - alpha * ap
     xo_ref[...] = x.astype(xo_ref.dtype)
     ro_ref[...] = r.astype(ro_ref.dtype)
-    rs_ref[0, 0] = jnp.sum(r * r)
+    rs_ref[...] = jnp.full(rs_ref.shape, jnp.sum(r * r), jnp.float32)
 
 
 def cg_update_batched_pallas(alpha: jax.Array, x: jax.Array, r: jax.Array,
@@ -125,23 +127,24 @@ def cg_update_batched_pallas(alpha: jax.Array, x: jax.Array, r: jax.Array,
     assert lane == LANE and rows % block_rows == 0
     nb = rows // block_rows
     vec = pl.BlockSpec((1, block_rows, LANE), lambda ni, i: (ni, i, 0))
-    scal = pl.BlockSpec((1, 1), lambda ni, i: (ni, 0))
+    scal = pl.BlockSpec((1, 1, 1), lambda ni, i: (ni, 0, 0))
     xo, ro, rs = pl.pallas_call(
         _update_batched_kernel,
         grid=(n, nb),
         in_specs=[scal, vec, vec, vec, vec],
-        out_specs=[vec, vec, pl.BlockSpec((1, 1), lambda ni, i: (ni, i))],
+        out_specs=[vec, vec, pl.BlockSpec((1, 1, 1, LANE),
+                                          lambda ni, i: (ni, i, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
                    jax.ShapeDtypeStruct(r.shape, r.dtype),
-                   jax.ShapeDtypeStruct((n, nb), jnp.float32)],
+                   jax.ShapeDtypeStruct((n, nb, 1, LANE), jnp.float32)],
         interpret=resolve_interpret(interpret),
-    )(jnp.asarray(alpha, jnp.float32).reshape(n, 1), x, r, p, ap)
-    return xo, ro, jnp.sum(rs, axis=1)
+    )(jnp.asarray(alpha, jnp.float32).reshape(n, 1, 1), x, r, p, ap)
+    return xo, ro, jnp.sum(rs[:, :, 0, 0], axis=1)
 
 
 def _xpay_batched_kernel(beta_ref, gate_ref, r_ref, p_ref, po_ref):
-    beta = beta_ref[0, 0]
-    gate = gate_ref[0, 0] != 0
+    beta = beta_ref[0, 0, 0]
+    gate = gate_ref[0, 0, 0] != 0
     p32 = p_ref[...].astype(jnp.float32)
     r32 = r_ref[...].astype(jnp.float32)
     po_ref[...] = jnp.where(gate, r32 + beta * p32, p32).astype(po_ref.dtype)
@@ -156,7 +159,7 @@ def cg_xpay_batched_pallas(beta: jax.Array, r: jax.Array, p: jax.Array,
     assert lane == LANE and rows % block_rows == 0
     nb = rows // block_rows
     vec = pl.BlockSpec((1, block_rows, LANE), lambda ni, i: (ni, i, 0))
-    scal = pl.BlockSpec((1, 1), lambda ni, i: (ni, 0))
+    scal = pl.BlockSpec((1, 1, 1), lambda ni, i: (ni, 0, 0))
     return pl.pallas_call(
         _xpay_batched_kernel,
         grid=(n, nb),
@@ -164,5 +167,5 @@ def cg_xpay_batched_pallas(beta: jax.Array, r: jax.Array, p: jax.Array,
         out_specs=vec,
         out_shape=jax.ShapeDtypeStruct(p.shape, p.dtype),
         interpret=resolve_interpret(interpret),
-    )(jnp.asarray(beta, jnp.float32).reshape(n, 1),
-      jnp.asarray(gate, jnp.float32).reshape(n, 1), r, p)
+    )(jnp.asarray(beta, jnp.float32).reshape(n, 1, 1),
+      jnp.asarray(gate, jnp.float32).reshape(n, 1, 1), r, p)

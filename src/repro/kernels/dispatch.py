@@ -23,9 +23,10 @@ swept offline by :mod:`repro.kernels.autotune`, and the winner per
 ``(backend, lattice_shape, nrhs, dtype)`` is checked in at
 ``kernels/tuning_cache.json``.  Kernel wrappers call :func:`pick_tile`
 at trace time; a cache miss (or ``REPRO_TUNING_CACHE=0``) falls back to
-the deterministic heuristic defaults, so golden/jaxpr tests stay bitwise
-with the cache cold or disabled.  All tile choices are bitwise-neutral
-by construction (they change data movement, never per-site FMA order) —
+the deterministic default (the largest tile that fits the scoped VMEM
+budget: ``wilson_dslash.kernel._fit_tile``), so golden/jaxpr tests stay
+bitwise with the cache cold or disabled.  All tile choices are
+bitwise-neutral by construction (they change data movement, never per-site FMA order) —
 the cache can only change *speed*, not results.
 
 Environment overrides (read at trace time):
@@ -86,7 +87,8 @@ class TileConfig:
     """One point in the dslash launch space (see DESIGN.md §13).
 
     ``bz``/``by``: z/y planes per block (None = heuristic default:
-    largest divisor of Z ≤ 4 for bz, full Y for by).  ``batch``: where
+    largest divisor of Z ≤ 4 for bz, full Y for by, shrunk until the
+    launch fits the scoped VMEM budget).  ``batch``: where
     the RHS-batch axis rides — "block" pins the whole batch inside every
     block (one gauge fetch feeds N spinor planes); "grid" makes it the
     trailing (fastest-varying) grid dimension, so consecutive steps

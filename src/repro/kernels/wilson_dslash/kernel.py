@@ -37,8 +37,9 @@ DMA overlaps the current plane's compute) — are all **bitwise-neutral**:
 they change HBM->VMEM data movement only, never the per-site FMA order.
 When none is given explicitly the wrappers consult the autotuner's
 checked-in ``kernels/tuning_cache.json`` (:func:`repro.kernels.dispatch.
-pick_tile`); a cold or disabled cache falls back to the deterministic
-historical defaults.
+pick_tile`).  On a cold or disabled cache a launch takes the largest
+tile that fits the scoped VMEM budget, starting from the historical
+default (bz ≤ 4, full Y), which stands wherever it fits.
 
 **Lowerings.**  ``interpret=None`` interprets on CPU and compiles
 (Mosaic) on GPU/TPU; ``interpret=False`` on CPU routes to the
@@ -88,7 +89,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.lattice import GAUGE_G, NCOL, NDIRS, NSPIN, SPINOR_S
 from repro.core.wilson import _projectors
-from repro.kernels.dispatch import pick_tile, resolve_lowering
+from repro.kernels.dispatch import (DEFAULT_TILE, pick_tile,
+                                    resolve_lowering)
 
 # ---------------------------------------------------------------------------
 # Trace-time tables for the spin-projection trick.
@@ -260,7 +262,9 @@ def _shift(lists, boundary, forward: bool, axis: int):
 
     Bitwise-equivalent to ``jnp.roll`` when the block spans the full
     extent — the Y-tiled launch switches rolls to shifts without changing
-    any per-site value or FMA order.
+    any per-site value or FMA order.  A block of extent 1 shifts to the
+    boundary plane itself (Mosaic has no zero-width vector for the empty
+    body slice).
     """
     out = []
     for r, row in enumerate(lists):
@@ -268,7 +272,9 @@ def _shift(lists, boundary, forward: bool, axis: int):
         for c, e in enumerate(row):
             b = boundary[r][c]
             n = e.shape[axis]
-            if forward:  # value at +1: drop plane 0, append boundary
+            if n == 1:     # the whole block is the plane that leaves
+                orow.append(b)
+            elif forward:  # value at +1: drop plane 0, append boundary
                 body = jax.lax.slice_in_dim(e, 1, n, axis=axis)
                 orow.append(jnp.concatenate([body, b], axis=axis))
             else:        # value at -1: prepend boundary, drop last
@@ -400,17 +406,96 @@ def _gauge_specs(t: int, z: int, bz: int, y: int, by: int, x: int,
     return specs
 
 
-def _resolve_tile(bz, by, batch, stream, t, z, y, x, nb, dtype):
-    """Explicit args > tuning cache > deterministic defaults.
+# Scoped VMEM of one launch.  Mosaic's default scoped limit is 16 MiB on
+# v5e.  The Pallas pipeline double-buffers every block, and a VMEM tile
+# pads a block's last two dims to (8·4/itemsize, 128).  The unrolled
+# kernel body keeps about 1,200 f32 (…, X) vectors live per (z, y) row of
+# the block, each padded to 128 lanes (Mosaic's scoped-VMEM reports for
+# the parity kernels at 24³×48); _BODY_ROWS adds a margin to that.
+SCOPED_VMEM_BYTES = 16 * 2**20
+VMEM_BUDGET_BYTES = SCOPED_VMEM_BYTES * 7 // 8
+_BODY_ROWS = 1400
+
+
+def _padded_bytes(shape: tuple[int, ...], dtype) -> int:
+    """VMEM bytes of one block: the last two dims padded to the tiling."""
+    item = jnp.dtype(dtype).itemsize
+    sub = 8 * 4 // item
+    *lead, rows, lanes = shape
+    return (int(np.prod(lead, dtype=np.int64)) * (-(-rows // sub) * sub)
+            * (-(-lanes // 128) * 128) * item)
+
+
+def launch_vmem_bytes(bz: int, by: int, y: int, x: int, nbb: int, dtype,
+                      gauge_dtype, gauge_centers: int,
+                      spinor_blocks: int) -> int:
+    """Scoped VMEM bytes of one plane-streaming launch: its double-buffered
+    blocks plus the kernel body's f32 working set.
+
+    ``nbb`` RHS ride in every spinor block ("block" placement; 1 for
+    "grid" or no batch); ``gauge_centers`` is the number of 4-direction
+    gauge operands (1 full lattice, 2 parity); ``spinor_blocks`` counts the
+    (bz, by) spinor blocks: center, t±1 and the output, plus an
+    accumulator where the launch has one.
+    """
+    sp = lambda zb, yb: _padded_bytes((nbb, zb, yb, SPINOR_S, x), dtype)
+    ga = lambda d, zb, yb: _padded_bytes((d, zb, yb, GAUGE_G, x),
+                                         gauge_dtype)
+    total = (spinor_blocks * sp(bz, by) + 2 * sp(1, by)
+             + gauge_centers * ga(NDIRS, bz, by) + ga(1, bz, by)
+             + ga(1, 1, by))
+    if by < y:
+        total += 2 * sp(bz, 1) + ga(1, bz, 1)
+    lane_row = 4 * (-(-x // 128) * 128)   # one f32 (…, X) row in VMEM
+    return 2 * total + _BODY_ROWS * nbb * bz * by * lane_row
+
+
+def _fit_tile(z: int, y: int, x: int, nb: int | None, dtype, gauge_dtype,
+              gauge_centers: int, spinor_blocks: int):
+    """The default tile: the largest block that fits the VMEM budget.
+
+    Candidates run from the historical default (bz = largest divisor of
+    Z ≤ 4, full Y) down through smaller bz, then smaller by, first with
+    the whole RHS batch in every block and then with the batch on the
+    grid.  The historical default stands wherever it fits.
+    """
+    bz0 = _pick_bz(z, None)
+    bzs = [c for c in reversed(_divisors(z)) if c <= bz0]
+    placements = ("block", "grid") if nb is not None else ("block",)
+    for batch in placements:
+        nbb = nb if (nb is not None and batch == "block") else 1
+        for by in reversed(_divisors(y)):
+            for bz in bzs:
+                if launch_vmem_bytes(bz, by, y, x, nbb, dtype, gauge_dtype,
+                                     gauge_centers, spinor_blocks) \
+                        <= VMEM_BUDGET_BYTES:
+                    return bz, by, batch
+    need = launch_vmem_bytes(1, 1, y, x, 1, dtype, gauge_dtype,
+                             gauge_centers, spinor_blocks)
+    raise ValueError(
+        f"no dslash tile fits VMEM for a (Z, Y, X) = {(z, y, x)} plane of "
+        f"{jnp.dtype(dtype).name} spinors (nrhs={nb or 1}): the smallest "
+        f"tile bz=1, by=1 needs {need} bytes against a budget of "
+        f"{VMEM_BUDGET_BYTES}")
+
+
+def _resolve_tile(bz, by, batch, stream, t, z, y, x, nb, dtype, *,
+                  gauge_dtype, gauge_centers: int = 1,
+                  spinor_blocks: int = 4):
+    """Explicit args > tuning cache > defaults.
 
     Any explicitly-passed knob disables the cache for the whole launch
     (tests and the autotuner stay deterministic); all-None consults
-    :func:`repro.kernels.dispatch.pick_tile`, whose miss path IS the
-    historical default.
+    :func:`repro.kernels.dispatch.pick_tile`, whose miss path takes the
+    largest tile that fits VMEM (:func:`_fit_tile`).
     """
     if bz is None and by is None and batch is None and stream is None:
         tile = pick_tile((t, z, y, x), nb or 1, dtype)
-        bz, by, batch, stream = tile.bz, tile.by, tile.batch, tile.stream
+        if tile == DEFAULT_TILE:
+            bz, by, batch = _fit_tile(z, y, x, nb, dtype, gauge_dtype,
+                                      gauge_centers, spinor_blocks)
+        else:
+            bz, by, batch, stream = tile.bz, tile.by, tile.batch, tile.stream
     batch = batch or "block"
     stream = stream or "blockspec"
     bz = _pick_bz(z, bz)
@@ -650,13 +735,14 @@ def dslash_pallas(up: jax.Array, pp: jax.Array, mass: float, *,
                                gamma5_in=gamma5_in, gamma5_out=gamma5_out)
 
     bz, by, batch, stream, y_tiled, grid_batch = _resolve_tile(
-        bz, by, batch, stream, t, z, y, x, nb, pp.dtype)
+        bz, by, batch, stream, t, z, y, x, nb, pp.dtype,
+        gauge_dtype=up.dtype)
     stream_db = stream == "db"
 
     psi_specs = _spinor_specs(t, z, bz, y, by, x, nb, grid_batch)
     gauge_specs = _gauge_specs(t, z, bz, y, by, x, grid_batch)
     if stream_db:
-        gauge_specs[0] = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
+        gauge_specs[0] = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
 
     kernel = functools.partial(_dslash_kernel, mass=float(mass),
                                twist=float(twist), g5in=bool(gamma5_in),
@@ -882,14 +968,16 @@ def _dslash_parity_pallas(u_out: jax.Array, u_nbr: jax.Array, pp: jax.Array,
             acc_twist=acc_twist, hop_twist=hop_twist)
 
     bz, by, batch, stream, y_tiled, grid_batch = _resolve_tile(
-        bz, by, batch, stream, t, z, y, x, nb, pp.dtype)
+        bz, by, batch, stream, t, z, y, x, nb, pp.dtype,
+        gauge_dtype=u_out.dtype, gauge_centers=2,
+        spinor_blocks=4 + (psi_acc is not None))
     stream_db = stream == "db"
 
     psi_specs = _spinor_specs(t, z, bz, y, by, x, nb, grid_batch)
     gauge_specs = _gauge_specs(t, z, bz, y, by, x, grid_batch)
     u_c, u_tm, u_zm = gauge_specs[0], gauge_specs[1], gauge_specs[2]
     if stream_db:
-        u_c = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
+        u_c = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
     in_specs = list(psi_specs) + [u_c, u_c, u_tm, u_zm]
     operands = [*([pp] * len(psi_specs)), u_out, *([u_nbr] * 3)]
     if y_tiled:

@@ -32,7 +32,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core.lattice import GAUGE_G, NCOL, NDIRS, NSPIN, SPINOR_S
+from repro.core.lattice import (GAUGE_G, NCOL, NDIRS, NSPIN, SPINOR_S,
+                                 exact_einsum)
 from repro.kernels.wilson_dslash.kernel import _TABLES
 
 # Whole-field axes (from the right): site fields unpack to
@@ -100,8 +101,8 @@ def _hop_half(ur, ui, pr, pi, mu: int, sign: str, g5in: bool, g5out: bool):
     # U† = conj(U)ᵀ via the transposed subscript + conjugation signs.
     sub = ("tzyabx,...tzyhbx->...tzyhax" if sign == "fwd"
            else "tzybax,...tzyhbx->...tzyhax")
-    e = lambda u, h: jnp.einsum(sub, u, h,
-                                preferred_element_type=jnp.float32)
+    e = lambda u, h: exact_einsum(sub, u, h,
+                                  preferred_element_type=jnp.float32)
     if sign == "fwd":
         gr = e(ur, hr) - e(ui, hi)
         gi = e(ur, hi) + e(ui, hr)

@@ -24,6 +24,7 @@ import argparse
 import json
 import sys
 
+from repro.compile_cache import enable_compile_cache
 from repro.serve.loadgen import WorkloadConfig, run_workload
 
 # family name -> its mu parameter when selected (0 for families without one)
@@ -136,6 +137,7 @@ def make_parser() -> argparse.ArgumentParser:
 
 def main(argv=None):
     args = make_parser().parse_args(argv)
+    enable_compile_cache()
     try:
         cfg = build_config(args)
     except ValueError as e:

@@ -32,11 +32,13 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import LatticeShape, dslash_flops, random_spinor
 from repro.core import plan as plan_mod
 from repro.core import solvers
 from repro.core.operators import dslash_g, get_operator, operator_names
 from repro.data import lattice_problem
+from repro.kernels.dispatch import resolve_lowering
 from repro.launch.mesh import make_debug_mesh
 
 # solver name -> (Krylov loop, precision policy); parity/backend/operator
@@ -66,6 +68,14 @@ def build_plan(args) -> plan_mod.SolverPlan:
         operator_family=args.operator, mu=args.mu,
         backend=args.backend, solver=loop, precision=precision,
         nrhs=args.nrhs, mesh=mesh)
+
+
+def _device_label(plan: plan_mod.SolverPlan) -> str:
+    """Where the rate above was measured: platform, device, lowering."""
+    dev = jax.devices()[0]
+    lowering = (resolve_lowering(plan.interpret) if plan.backend == "pallas"
+                else "jnp reference")
+    return f"{dev.platform}, {dev.device_kind}, {lowering}"
 
 
 def main(argv=None):
@@ -117,6 +127,7 @@ def main(argv=None):
                         "iterates to (deeper than --tol mines more "
                         "spectrum)")
     args = p.parse_args(argv)
+    enable_compile_cache()
     if args.resume and args.checkpoint_dir is None:
         p.error("--resume requires --checkpoint-dir")
     if args.deflate > 0 and (args.resume or args.checkpoint_dir):
@@ -255,7 +266,7 @@ def main(argv=None):
           f"matvecs={int(jnp.max(mv))} "
           f"(total {int(jnp.sum(mv))} across {n_systems} RHS) "
           f"max_rel_res={rel:.2e} time={dt:.2f}s "
-          f"~{flops/dt/1e9:.2f} GFLOP/s (CPU, interpret-mode kernels)")
+          f"~{flops/dt/1e9:.2f} GFLOP/s ({_device_label(plan)})")
     return 0 if ok else 1
 
 

@@ -34,7 +34,7 @@ def collect_eqns(jaxpr, *, into_pallas: bool = False):
     ``pallas_call`` equations unless ``into_pallas`` — equations inside a
     kernel run from VMEM and must not count as HBM passes.
     """
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     if isinstance(jaxpr, ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
@@ -59,7 +59,7 @@ def pallas_call_eqns(jaxpr):
 # equations that collect_eqns already walks, so they are not HBM passes
 # themselves.
 _CONTAINER_PRIMS = frozenset({
-    "pjit", "closed_call", "core_call", "xla_call", "remat", "remat2",
+    "jit", "pjit", "closed_call", "core_call", "xla_call", "remat", "remat2",
     "custom_jvp_call", "custom_vjp_call", "custom_vjp_call_jaxpr",
     "while", "scan", "cond", "checkpoint", "named_call",
 })

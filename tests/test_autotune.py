@@ -22,8 +22,11 @@ from repro.core import (LatticeShape, pack_gauge, pack_spinor, random_gauge,
 from repro.kernels import autotune, dispatch
 from repro.kernels.dispatch import (DEFAULT_TILE, TileConfig, cache_key,
                                     parse_tile, pick_tile, save_tuning_cache)
-from repro.kernels.wilson_dslash.kernel import (_divisors, _pick_by,
-                                                _pick_bz, dslash_pallas)
+from repro.kernels.wilson_dslash.kernel import (VMEM_BUDGET_BYTES,
+                                                _divisors, _fit_tile,
+                                                _pick_by, _pick_bz,
+                                                dslash_pallas,
+                                                launch_vmem_bytes)
 from repro.kernels.wilson_dslash.ops import schur_normal_op
 from repro.testing import pallas_call_eqns
 
@@ -90,6 +93,23 @@ def test_pick_by_error_lists_legal_values():
     with pytest.raises(ValueError, match=r"by=3 does not tile the Y extent "
                                          r"4.*\[1, 2, 4\]"):
         _pick_by(4, 3)
+
+
+def test_fit_tile_shrinks_to_vmem_and_names_the_shape():
+    """The default tile: the historical default where it fits, a
+    smaller block where it does not (24³×48 parity fields), and an error
+    naming the plane when not even bz=1, by=1 fits."""
+    f32 = jnp.float32
+    parity = dict(gauge_centers=2, spinor_blocks=4)
+    assert _fit_tile(2, 2, 4, None, f32, f32, **parity) == (2, 2, "block")
+    bz, by, batch = _fit_tile(24, 24, 12, None, f32, f32, **parity)
+    assert (batch, 24 % bz, 24 % by) == ("block", 0, 0)
+    assert launch_vmem_bytes(bz, by, 24, 12, 1, f32, f32,
+                             **parity) <= VMEM_BUDGET_BYTES
+    assert launch_vmem_bytes(_pick_bz(24, None), 24, 24, 12, 1, f32, f32,
+                             **parity) > VMEM_BUDGET_BYTES
+    with pytest.raises(ValueError, match=r"\(Z, Y, X\) = \(24, 24, 4096\)"):
+        _fit_tile(24, 24, 4096, None, f32, f32, **parity)
 
 
 def test_tile_config_validates():

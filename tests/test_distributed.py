@@ -19,10 +19,8 @@ from repro.core import *
 from repro.core import distributed as dist
 from repro.core.wilson import dslash_packed
 
-from repro.compat import make_mesh, shard_map
-
 out = {}
-mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
 lat = LatticeShape(4, 4, 4, 8)
 ku, kp = jax.random.split(jax.random.PRNGKey(3))
 U = random_gauge(ku, lat); psi = random_spinor(kp, lat); m = 0.3
@@ -30,14 +28,14 @@ up, pp = pack_gauge(U), pack_spinor(psi)
 upd, ppd = dist.shard_lattice_fields(mesh, up, pp)
 
 psi_spec, gauge_spec, sharded = dist.lattice_specs(mesh)
-f = jax.jit(shard_map(lambda u, p: dist.dslash_halo(u, p, m, sharded),
+f = jax.jit(jax.shard_map(lambda u, p: dist.dslash_halo(u, p, m, sharded),
                       mesh=mesh, in_specs=(gauge_spec, psi_spec),
                       out_specs=psi_spec))
 err = float(jnp.max(jnp.abs(f(upd, ppd) - dslash_packed(up, pp, m))))
 out["halo_dslash_err"] = err
 
 # the TPU path: Pallas plane-streaming kernel as the bulk stencil
-fk = jax.jit(shard_map(
+fk = jax.jit(jax.shard_map(
     lambda u, p: dist.dslash_halo(u, p, m, sharded, use_pallas=True),
     mesh=mesh, in_specs=(gauge_spec, psi_spec), out_specs=psi_spec,
     check_vma=False))
@@ -58,7 +56,9 @@ from repro.models import steps as S
 from repro.optim import AdamWConfig
 from repro.data import SyntheticLM
 from jax.sharding import NamedSharding, PartitionSpec as P
-mesh2 = make_mesh((2, 2), ("data", "model"))
+# the LM steps shard by GSPMD propagation: Auto axes
+mesh2 = jax.make_mesh((2, 2), ("data", "model"),
+                      axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg = configs.get_smoke("glm4-9b")
 opt = AdamWConfig(lr=1e-3)
 state = S.init_train_state(cfg, jax.random.PRNGKey(0), opt)
@@ -90,6 +90,8 @@ bb = jnp.stack([random_spinor(jax.random.fold_in(kp, i), lat)
 pl_eo = plan_mod.SolverPlan(operator="eo-schur", backend="reference",
                             solver="pipecg", nrhs=N, mesh=mesh)
 xsh, stsh = plan_mod.solve(pl_eo, U, bb, m, tol=1e-6, maxiter=500)
+# gather to the host: the checks below apply the global-view operator
+xsh = jax.device_get(xsh)
 xs1, sts1 = solve_wilson_eo_batched(U, bb, m, tol=1e-6, maxiter=500,
                                     use_pallas=False)
 res = jax.vmap(lambda xx, bv: dslash(U, xx, m) - bv)(xsh, bb)
@@ -112,7 +114,7 @@ psi_spec2, gauge_spec2, sharded2 = dist.lattice_specs(mesh)
 upe_d = jax.device_put(upe, NamedSharding(mesh, gauge_spec2))
 upo_d = jax.device_put(upo, NamedSharding(mesh, gauge_spec2))
 pe_d = jax.device_put(pe, NamedSharding(mesh, psi_spec2))
-fk = jax.jit(shard_map(
+fk = jax.jit(jax.shard_map(
     lambda ue, uo, p: dist.schur_normal_op_halo(ue, uo, p, m, sharded2,
                                                 use_pallas=True),
     mesh=mesh, in_specs=(gauge_spec2, gauge_spec2, psi_spec2),
@@ -140,7 +142,7 @@ def local_pipecg(ue, uo, be, bo):
                             fused_dots=fused)
     return x_e
 
-jx = jax.make_jaxpr(shard_map(
+jx = jax.make_jaxpr(jax.shard_map(
     local_pipecg, mesh=mesh,
     in_specs=(gauge_spec2, gauge_spec2, bspec, bspec),
     out_specs=bspec, check_vma=False))(upe, upo, pbe, pbo)

@@ -71,16 +71,14 @@ ck = sys.argv[1]
 cfg = configs.get_smoke("glm4-9b")
 opt = AdamWConfig()
 state = S.init_train_state(cfg, jax.random.PRNGKey(0), opt)
-
-from repro.compat import make_mesh
-mesh_a = make_mesh((4, 2), ("data", "model"))
+mesh_a = jax.make_mesh((4, 2), ("data", "model"))
 specs = S.state_specs(cfg, jax.eval_shape(lambda: state))
 sh_a = jax.tree.map(lambda sp: NamedSharding(mesh_a, sp), specs,
                     is_leaf=lambda x: isinstance(x, P))
 state_a = jax.device_put(state, sh_a)
 save_checkpoint(ck, 1, state_a)
 
-mesh_b = make_mesh((2, 4), ("data", "model"))
+mesh_b = jax.make_mesh((2, 4), ("data", "model"))
 sh_b = jax.tree.map(lambda sp: NamedSharding(mesh_b, sp), specs,
                     is_leaf=lambda x: isinstance(x, P))
 restored = restore_checkpoint(ck, 1, jax.eval_shape(lambda: state), sh_b)

@@ -9,6 +9,7 @@ from repro.core import (LatticeShape, field_dot, field_norm2, merge_eo,
                         merge_eo_gauge, pack_gauge, pack_spinor, parity_masks,
                         random_gauge, random_spinor, split_eo, split_eo_gauge,
                         unit_gauge, unpack_gauge, unpack_spinor)
+from repro.core.lattice import _project_su3
 from repro.testing import maybe_hypothesis
 
 given, settings, st = maybe_hypothesis()
@@ -23,6 +24,19 @@ def test_su3_unitarity_and_det(rng):
     assert jnp.max(jnp.abs(uu - eye)) < 5e-6
     det = jnp.linalg.det(u)
     assert jnp.max(jnp.abs(det - 1.0)) < 5e-6
+
+
+def test_random_gauge_is_drawn_on_the_host(rng):
+    """The field is drawn on the host's CPU backend and placed, uncommitted,
+    on the default device: the same field as a plain draw on the CPU."""
+    u = random_gauge(rng, LAT)
+    assert not u.committed
+    kr, ki = jax.random.split(rng)
+    shape = (4,) + LAT.dims + (3, 3)
+    z = (jax.random.normal(kr, shape, dtype=jnp.float32)
+         + 1j * jax.random.normal(ki, shape, dtype=jnp.float32))
+    assert np.array_equal(np.asarray(u),
+                          np.asarray(_project_su3(z.astype(jnp.complex64))))
 
 
 def test_unit_gauge_is_identity():

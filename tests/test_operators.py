@@ -335,12 +335,11 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import jax, jax.numpy as jnp
-from repro.compat import make_mesh
 from repro.core import (LatticeShape, SolverPlan, random_gauge,
                         random_spinor, solve_plan)
 from repro.core.operators import dslash_g
 
-mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
 lat = LatticeShape(4, 4, 4, 8)
 m, mu, tol, N = 0.1, 0.3, 1e-6, 2
 ku, kb = jax.random.split(jax.random.PRNGKey(7))
@@ -350,6 +349,8 @@ b = jnp.stack([random_spinor(jax.random.fold_in(kb, i), lat)
 psh = SolverPlan(operator="eo-schur", operator_family="twisted-mass",
                  mu=mu, solver="pipecg", nrhs=N, mesh=mesh)
 xsh, stsh = solve_plan(psh, u, b, m, tol=tol, maxiter=500)
+# gather to the host: the checks below apply the global-view operator
+xsh = jax.device_get(xsh)
 p1 = SolverPlan(operator="eo-schur", operator_family="twisted-mass",
                 mu=mu, nrhs=N)
 x1, _ = solve_plan(p1, u, b, m, tol=tol, maxiter=500)

@@ -3,6 +3,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import (LatticeShape, dslash, dslash_dagger, field_dot,
                         merge_eo, pack_gauge, pack_spinor, random_gauge,
@@ -159,3 +160,31 @@ def test_schur_normal_op_hpd(rng):
 
 def test_flops_constant():
     assert DSLASH_FLOPS_PER_SITE == 1320  # the standard Wilson count
+
+
+def _contractions(fn, *args):
+    """Precision attributes of every dot_general in ``fn``'s lowering."""
+    import re
+    text = jax.jit(fn).lower(*args).as_text()
+    return re.findall(r"stablehlo\.dot_general.*", text)
+
+
+@pytest.mark.parametrize("op", ["natural", "packed", "eo", "xla"])
+def test_reference_contractions_ask_full_precision(op):
+    """A TPU contracts f32 in one bf16 pass by default (~1e-3 relative
+    error, far above a 1e-6 solve tolerance); every contraction of the
+    jnp operators — the verification oracle among them — asks HIGHEST."""
+    from repro.kernels.wilson_dslash.xla import dslash_xla
+    u = jnp.zeros((4,) + LAT.dims + (3, 3), jnp.complex64)
+    psi = jnp.zeros(LAT.dims + (4, 3), jnp.complex64)
+    up, pp = pack_gauge(u), pack_spinor(psi)
+    ue, uo = split_eo_gauge(u)
+    fn, args = {
+        "natural": (lambda u, p: dslash(u, p, MASS), (u, psi)),
+        "packed": (lambda u, p: dslash_packed(u, p, MASS), (up, pp)),
+        "eo": (lambda a, b, p: dslash_eo(a, b, p), (ue, uo, split_eo(psi)[1])),
+        "xla": (lambda u, p: dslash_xla(u, p, MASS), (up, pp)),
+    }[op]
+    dots = _contractions(fn, *args)
+    assert dots
+    assert all("precision = [HIGHEST, HIGHEST]" in d for d in dots), dots
