@@ -30,20 +30,6 @@ Usage:  check_solver_regression.py [BENCH_solvers.json] [baseline.json]
         check_solver_regression.py --serve [BENCH_serve.json] [baseline.json]
         check_solver_regression.py --chaos [BENCH_serve.json] [baseline.json]
         check_solver_regression.py --resume [BENCH_resume.json] [baseline.json]
-        check_solver_regression.py --perf [BENCH_perf_trajectory.json]
-
-``--perf`` guards the compiled-backend perf trajectory (produced by
-``benchmarks/launch_bench.sh`` -> ``perf_trajectory.py``): within the
-LATEST snapshot every compiled Pallas dslash row must beat the jnp
-reference at equal N on the same lattice (the interpret-mode 79-vs-1179
-inversion stays closed — a machine-independent invariant), every gated
-row must carry an achieved-vs-roofline ``bw_fraction``, and versus the
-previous snapshot on the SAME device_kind the warm sites·RHS/s and
-``bw_fraction`` must not collapse below ``PERF_SLACK`` of their prior
-values (generous: shared-runner wall-clock is noisy and absolute
-throughput varies between hosts of one device_kind; the gate exists to
-catch structural collapses — losing a compiled lowering is 10x+ —
-while iteration counts remain the precise signal).
 
 ``--generate`` runs the smoke solves itself (no full benchmark harness
 needed) and guards the result — the BLOCKING ``bench-guard`` CI job and
@@ -70,12 +56,6 @@ import os
 import sys
 
 SLACK_ITERS = 2  # float-reduction jitter across platforms, not a budget
-
-# --perf: warm throughput / bw_fraction may not fall below this fraction
-# of the previous same-device snapshot (wall-clock on shared runners is
-# noisy, so the slack is deliberately generous — a real regression from
-# e.g. losing the compiled lowering is 10x+, far past any noise)
-PERF_SLACK = 0.5
 
 # section -> guarded iteration-count keys inside it
 GUARDED_SECTIONS = {
@@ -491,72 +471,6 @@ def _check_chaos(table, cur, base):
                   "OK" if v.get("passed") else "REGRESSION")
 
 
-def _check_perf(table: _Table, doc: dict) -> None:
-    """Gate the compiled-backend perf trajectory (see module docstring)."""
-    snaps = doc.get("snapshots") or []
-    if not snaps:
-        table.missing("perf", "snapshots", ">=1")
-        return
-    latest = snaps[-1]
-    entries = {e["name"]: e for e in latest.get("entries", [])}
-
-    # --- invariant: the compiled Pallas lane exists and is non-interpret
-    pallas = {n: e for n, e in entries.items()
-              if n.startswith("dslash_pallas_compiled")}
-    if not pallas:
-        table.missing("perf", "dslash_pallas_compiled*", "present")
-    for name, e in sorted(pallas.items()):
-        interp = bool(e.get("interpret", False))
-        table.add("perf", f"{name}.interpret", False, interp, "-",
-                  "OK" if not interp else "REGRESSION")
-        # --- invariant: compiled Pallas beats the jnp reference at the
-        # same N on the same lattice (names end with the lattice dims)
-        lattice = name.rsplit("_", 1)[-1]
-        n = int(e.get("n_rhs", 1))
-        jnp_name = (f"dslash_jnp_{lattice}" if n == 1
-                    else f"dslash_jnp_nrhs{n}_{lattice}")
-        ref = entries.get(jnp_name)
-        if ref is None:
-            table.missing("perf", jnp_name, "present")
-            continue
-        got = float(e.get("sites_rhs_per_s", 0.0))
-        need = float(ref.get("sites_rhs_per_s", 0.0))
-        table.add("perf", f"{name}>=jnp", f">={need:.0f}", round(got),
-                  round(need), "OK" if got >= need else "REGRESSION")
-
-    # --- invariant: every gated row carries a roofline fraction
-    for name, e in sorted(entries.items()):
-        if name.startswith("dslash_") and "bw_fraction" not in e:
-            table.missing("perf", f"{name}.bw_fraction", "present")
-
-    # --- trajectory: compare against the previous same-device snapshot.
-    # bw_fraction is normalized by the running host's OWN measured peak,
-    # so it travels between runners of the same device_kind; absolute
-    # sites_rhs_per_s is host-dependent, which is what the generous
-    # PERF_SLACK is for — the regression this catches is structural
-    # (losing a compiled lowering is 10x+), not runner jitter.
-    prev = next((s for s in reversed(snaps[:-1])
-                 if s.get("device_kind") == latest.get("device_kind")
-                 and s.get("platform") == latest.get("platform")), None)
-    if prev is None:
-        table.add("perf", "trajectory", "first snapshot",
-                  "first snapshot", "-", "OK")
-        return
-    prev_entries = {e["name"]: e for e in prev.get("entries", [])}
-    for name, e in sorted(entries.items()):
-        p = prev_entries.get(name)
-        if p is None:
-            continue
-        for metric in ("sites_rhs_per_s", "bw_fraction"):
-            if metric not in e or not p.get(metric):
-                continue
-            floor = PERF_SLACK * float(p[metric])
-            got = float(e[metric])
-            table.add("perf", f"{name}.{metric}",
-                      f">={floor:.3g}", f"{got:.3g}", f"{floor:.3g}",
-                      "OK" if got >= floor else "REGRESSION")
-
-
 def _load(path: str, what: str) -> dict | None:
     try:
         with open(path) as f:
@@ -569,20 +483,6 @@ def _load(path: str, what: str) -> dict | None:
 def main(argv: list[str]) -> int:
     base_path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                              "BENCH_solvers_baseline.json")
-    if len(argv) > 1 and argv[1] == "--perf":
-        traj_path = argv[2] if len(argv) > 2 else os.environ.get(
-            "BENCH_PERF_TRAJECTORY_JSON", "BENCH_perf_trajectory.json")
-        doc = _load(traj_path, "perf trajectory")
-        if doc is None:
-            return 1
-        table = _Table()
-        _check_perf(table, doc)
-        table.print()
-        if table.failed:
-            print("perf guard: FAILED — see the non-OK rows above")
-            return 1
-        print("perf guard: passed")
-        return 0
     if len(argv) > 1 and argv[1] in ("--serve", "--chaos", "--resume"):
         mode = argv[1].lstrip("-")
         default_report = ("BENCH_resume.json" if mode == "resume"

@@ -11,9 +11,7 @@
 #   BENCH_BACKEND=tpu benchmarks/launch_bench.sh  # device run
 #   benchmarks/launch_bench.sh --only dslash      # extra run.py args pass through
 #
-# Produces BENCH_dslash.json / BENCH_solvers.json in the CWD and appends
-# the snapshot for the current commit to BENCH_perf_trajectory.json
-# (gated in CI by check_solver_regression.py --perf).
+# Produces BENCH_dslash.json / BENCH_solvers.json in the CWD.
 set -euo pipefail
 
 REPO="$(cd "$(dirname "${BASH_SOURCE[0]}")/.." && pwd)"
@@ -49,4 +47,3 @@ if [ "$#" -eq 0 ]; then
   ARGS+=("--only" "dslash" "solvers")
 fi
 python "${REPO}/benchmarks/run.py" "${ARGS[@]}" "$@"
-python "${REPO}/benchmarks/perf_trajectory.py" --append

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.core import phases
 from repro.core.operators import apply_igamma5_packed, schur_launch_coeffs
 from repro.core.wilson import (apply_gamma5_packed, dslash_packed,
                                hop_term_packed)
@@ -188,11 +189,13 @@ def parity_hop_halo(which: str, u_e: jax.Array, u_o: jax.Array,
     # module-level import here would be circular.
     from repro.kernels.wilson_dslash import ops as wops
 
-    out = wops.hop_block(u_e, u_o, pp, which=which, gamma5_in=gamma5_in,
-                         gamma5_out=gamma5_out, psi_acc=psi_acc,
-                         acc_coeff=acc_coeff, hop_coeff=hop_coeff,
-                         acc_twist=acc_twist, hop_twist=hop_twist,
-                         use_pallas=use_pallas, bz=bz, interpret=interpret)
+    with phases.scope(phases.HOP_EO if which == "eo" else phases.HOP_OE):
+        out = wops.hop_block(u_e, u_o, pp, which=which, gamma5_in=gamma5_in,
+                             gamma5_out=gamma5_out, psi_acc=psi_acc,
+                             acc_coeff=acc_coeff, hop_coeff=hop_coeff,
+                             acc_twist=acc_twist, hop_twist=hop_twist,
+                             use_pallas=use_pallas, bz=bz,
+                             interpret=interpret)
     u_out, u_nbr = (u_e, u_o) if which == "eo" else (u_o, u_e)
     batch = pp.ndim - 5  # 0 or 1 leading RHS-batch axes
     hc = jnp.asarray(hop_coeff, jnp.float32)
@@ -319,12 +322,14 @@ def make_psum_dots(mesh: Mesh, batched: bool = False):
     axes = tuple(mesh.axis_names)
     lead = 1 if batched else 0
 
+    @phases.scoped(phases.PSUM)
     def dot(a, b):
         red = tuple(range(lead, a.ndim))
         local = jnp.sum(a.astype(jnp.float32) * b.astype(jnp.float32),
                         axis=red)
         return lax.psum(local, axes)
 
+    @phases.scoped(phases.PSUM)
     def norm2(a):
         a32 = a.astype(jnp.float32)
         return lax.psum(jnp.sum(a32 * a32, axis=tuple(range(lead, a.ndim))),
@@ -347,6 +352,7 @@ def make_fused_psum_dots(mesh: Mesh, batched: bool = False):
     axes = tuple(mesh.axis_names)
     lead = 1 if batched else 0
 
+    @phases.scoped(phases.PSUM)
     def fused_dots(r, w):
         red = tuple(range(lead, r.ndim))
         r32, w32 = r.astype(jnp.float32), w.astype(jnp.float32)

@@ -29,7 +29,7 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core import solvers
+from repro.core import phases, solvers
 from repro.core.lattice import (field_dot, field_norm2, merge_eo, pack_gauge,
                                 pack_spinor, split_eo, split_eo_gauge,
                                 unpack_spinor)
@@ -60,7 +60,8 @@ def eo_operators(u: Array, mass, r: float = 1.0,
     blocks.  The hop blocks ``d_eo``/``d_oe`` are operator-agnostic
     transport and never see the twist.
     """
-    u_e, u_o = split_eo_gauge(u)
+    with phases.scope(phases.GAUGE_LAYOUT):
+        u_e, u_o = split_eo_gauge(u)
     site = SiteTerm(mass + 4.0 * r, twist)
     return EOOperators(
         dhat=lambda v: schur_op_g(u_e, u_o, v, mass, r=r, twist=twist),
@@ -116,8 +117,9 @@ def eo_operators_packed(u: Array, mass, r: float = 1.0, *,
     # module-level import here would be circular.
     from repro.kernels.wilson_dslash import ops as wops
 
-    u_e, u_o = split_eo_gauge(u)
-    upe, upo = pack_gauge(u_e), pack_gauge(u_o)
+    with phases.scope(phases.GAUGE_LAYOUT):
+        u_e, u_o = split_eo_gauge(u)
+        upe, upo = pack_gauge(u_e), pack_gauge(u_o)
     site = SiteTerm(mass + 4.0 * r, twist)
     kw = dict(bz=bz, interpret=interpret, use_pallas=use_pallas)
     return EOOperators(
@@ -130,6 +132,7 @@ def eo_operators_packed(u: Array, mass, r: float = 1.0, *,
         u_e=upe, u_o=upo)
 
 
+@phases.scoped(phases.SCHUR_RHS)
 def schur_rhs(ops: EOOperators, b_e: Array, b_o: Array) -> Array:
     """The Schur normal-equation RHS ``D̂†(b_e − D_eo M_oo⁻¹ b_o)``.
 
@@ -141,6 +144,7 @@ def schur_rhs(ops: EOOperators, b_e: Array, b_o: Array) -> Array:
     return ops.dhat_dag(b_e - ops.d_eo(ops.m_inv(b_o)))
 
 
+@phases.scoped(phases.BACK_SUBSTITUTE)
 def back_substitute_odd(ops: EOOperators, b_o: Array, x_e: Array) -> Array:
     """Recover the odd half field: ``x_o = M_oo⁻¹ (b_o − D_oe x_e)``."""
     return ops.m_inv(b_o - ops.d_oe(x_e))
@@ -185,10 +189,12 @@ def eo_context(u: Array, mass, *, r: float = 1.0, twist: float = 0.0,
         ops = eo_operators_packed(u, mass, r=r, twist=twist, bz=bz,
                                   interpret=interpret)
 
+        @phases.scoped(phases.RHS_LAYOUT)
         def prepare(b: Array) -> tuple[Array, Array]:
             b_e, b_o = (jax.vmap(split_eo)(b) if batched else split_eo(b))
             return pack_spinor(b_e), pack_spinor(b_o)
 
+        @phases.scoped(phases.MERGE)
         def finish(x_e: Array, x_o: Array) -> Array:
             xe = unpack_spinor(x_e, dtype=out_dtype)
             xo = unpack_spinor(x_o, dtype=out_dtype)
@@ -203,6 +209,7 @@ def eo_context(u: Array, mass, *, r: float = 1.0, twist: float = 0.0,
                          engine=engine, packed=True, batched=batched)
 
     ops = eo_operators(u, mass, r=r, twist=twist)
+    prepare, finish = split_eo, merge_eo
     if batched:
         # natural-layout blocks are single-RHS; vmap them (m_inv is
         # elementwise and batch-transparent already)
@@ -210,12 +217,11 @@ def eo_context(u: Array, mass, *, r: float = 1.0, twist: float = 0.0,
                            dhat_dag=jax.vmap(ops.dhat_dag),
                            d_eo=jax.vmap(ops.d_eo),
                            d_oe=jax.vmap(ops.d_oe))
-
-        return EOContext(ops=ops, prepare=jax.vmap(split_eo),
-                         finish=jax.vmap(merge_eo), engine=None,
-                         packed=False, batched=True)
-    return EOContext(ops=ops, prepare=split_eo, finish=merge_eo,
-                     engine=None, packed=False, batched=False)
+        prepare, finish = jax.vmap(split_eo), jax.vmap(merge_eo)
+    return EOContext(ops=ops,
+                     prepare=phases.scoped(phases.RHS_LAYOUT)(prepare),
+                     finish=phases.scoped(phases.MERGE)(finish),
+                     engine=None, packed=False, batched=batched)
 
 
 # ---------------------------------------------------------------------------

@@ -57,7 +57,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import distributed as dist
-from repro.core import solvers
+from repro.core import phases, solvers
 from repro.core.eo import (EOContext, back_substitute_odd, eo_context,
                            schur_rhs)
 from repro.core.lattice import (complex_to_real_pair, field_dot,
@@ -285,6 +285,7 @@ def _true_residual_mesh(plan: SolverPlan, u: Array, b: Array, mass,
         out_specs=(P(), P()), check_vma=False))(u, b, x)
 
 
+@phases.scoped(phases.VERIFY)
 def _attach_verification(plan: SolverPlan, u: Array, b: Array, mass,
                          x: Array, stats: solvers.SolveStats, tol,
                          layout: str = "natural") -> solvers.SolveStats:
@@ -529,13 +530,12 @@ def _solve_eo(plan, u, b, mass, *, tol, maxiter, dot, norm2,
         # back-substitution as cgnr_eo, the pipelined loop in the middle
         # (pipecg has no update/xpay engine hooks — its three-term
         # recurrence is a different vector-algebra shape).
-        b_hat = b_e - ops.d_eo(ops.m_inv(b_o))
         x_e, stats = solvers.pipecg(
-            lambda v: ops.dhat_dag(ops.dhat(v)), ops.dhat_dag(b_hat),
+            lambda v: ops.dhat_dag(ops.dhat(v)), schur_rhs(ops, b_e, b_o),
             tol=tol, maxiter=maxiter,
             residual_replacement_every=residual_replacement_every,
             dot=dot, norm2=norm2, batched=ctx.batched)
-        x_o = ops.m_inv(b_o - ops.d_oe(x_e))
+        x_o = back_substitute_odd(ops, b_o, x_e)
     elif plan.solver == "blockcg":
         rhs = schur_rhs(ops, b_e, b_o)
         x0 = None
@@ -580,8 +580,9 @@ def _solve_eo_mp(plan, u, b, mass, *, tol, maxiter, inner_tol,
         high = b_e.dtype
         # one up-front rounding of the links — the low operator's gauge
         # reads then stream bf16 (half the gauge HBM traffic), wide inside.
-        u_e_lo = ops.u_e.astype(low_dtype)
-        u_o_lo = ops.u_o.astype(low_dtype)
+        with phases.scope(phases.GAUGE_LAYOUT):
+            u_e_lo = ops.u_e.astype(low_dtype)
+            u_o_lo = ops.u_o.astype(low_dtype)
         kkw = dict(twist=twist, bz=plan.bz, interpret=plan.interpret)
 
         def a_low(w):  # low storage in/out, f32 registers inside
@@ -599,7 +600,8 @@ def _solve_eo_mp(plan, u, b, mass, *, tol, maxiter, inner_tol,
             pair = complex_to_real_pair(w, dtype=low_dtype)
             return real_pair_to_complex(pair, dtype=w.dtype)
 
-        u_e_lo, u_o_lo = round_links(ops.u_e), round_links(ops.u_o)
+        with phases.scope(phases.GAUGE_LAYOUT):
+            u_e_lo, u_o_lo = round_links(ops.u_e), round_links(ops.u_o)
 
         def a_low(w):  # bf16 real-pair in/out, wide inside
             v = real_pair_to_complex(w, dtype=high)
@@ -638,8 +640,7 @@ def _solve_full(plan, u, b, mass, *, tol, maxiter, inner_tol,
     from repro.kernels.wilson_dslash import ops as wops
 
     packed_in = layout == "packed"
-    up = u if packed_in else pack_gauge(u)
-    pp = b if packed_in else pack_spinor(b)
+    up, pp = _packed_fields(u, b, packed_in)
     m = float(mass)
     kw = dict(twist=_family_site(plan, mass).twist, bz=plan.bz,
               interpret=plan.interpret,
@@ -679,7 +680,22 @@ def _solve_full(plan, u, b, mass, *, tol, maxiter, inner_tol,
             x = x.astype(pp.dtype)
     if packed_in:
         return x, stats
-    return unpack_spinor(x, dtype=b.dtype), stats
+    return _unpacked(x, b.dtype), stats
+
+
+def _packed_fields(u, b, packed_in: bool):
+    """(u, b) in the packed working layout of the full operator."""
+    if packed_in:
+        return u, b
+    with phases.scope(phases.GAUGE_LAYOUT):
+        up = pack_gauge(u)
+    with phases.scope(phases.RHS_LAYOUT):
+        return up, pack_spinor(b)
+
+
+@phases.scoped(phases.MERGE)
+def _unpacked(x, dtype):
+    return unpack_spinor(x, dtype=dtype)
 
 
 def _solve_full_sharded(plan, u, b, mass, *, tol, maxiter, inner_tol,
@@ -690,8 +706,7 @@ def _solve_full_sharded(plan, u, b, mass, *, tol, maxiter, inner_tol,
 
     mesh = plan.mesh
     packed_in = layout == "packed"
-    up = u if packed_in else pack_gauge(u)
-    pp = b if packed_in else pack_spinor(b)
+    up, pp = _packed_fields(u, b, packed_in)
     psi_spec, gauge_spec, sharded = dist.lattice_specs(mesh, plan.axis_map)
     pdot, pnorm2 = dist.make_psum_dots(mesh)
     use_pallas = plan.backend == "pallas"
@@ -740,7 +755,7 @@ def _solve_full_sharded(plan, u, b, mass, *, tol, maxiter, inner_tol,
     x, stats = jax.jit(shmapped)(up, pp)
     if packed_in:
         return x, stats
-    return unpack_spinor(x, dtype=b.dtype), stats
+    return _unpacked(x, b.dtype), stats
 
 
 # ---------------------------------------------------------------------------
@@ -766,10 +781,15 @@ def _solve_eo_sharded(plan, u, b, mass, *, tol, maxiter,
     solver = _sharded_eo_solver(plan, float(mass), float(tol), int(maxiter),
                                 int(residual_replacement_every))
     x_e, x_o, stats = solver(upe, upo, pb_e, pb_o)
-    xe = unpack_spinor(x_e, dtype=b.dtype)
-    xo = unpack_spinor(x_o, dtype=b.dtype)
-    x = jax.vmap(merge_eo)(xe, xo) if batched else merge_eo(xe, xo)
-    return x, stats
+    return _merged(x_e, x_o, b.dtype, batched), stats
+
+
+@phases.scoped(phases.MERGE)
+def _merged(x_e: Array, x_o: Array, dtype, batched: bool) -> Array:
+    """The natural-layout solution from packed parity half fields."""
+    xe = unpack_spinor(x_e, dtype=dtype)
+    xo = unpack_spinor(x_o, dtype=dtype)
+    return jax.vmap(merge_eo)(xe, xo) if batched else merge_eo(xe, xo)
 
 
 def _eo_sharded_prep(plan: SolverPlan, u: Array, b: Array):
@@ -802,10 +822,12 @@ def _eo_sharded_prep(plan: SolverPlan, u: Array, b: Array):
                 f"extent {ext} over {n} '{ax}' shards")
 
     # global prep in natural layout, then shard the packed parity fields
-    u_e, u_o = split_eo_gauge(u)
-    upe, upo = pack_gauge(u_e), pack_gauge(u_o)
-    b_e, b_o = (jax.vmap(split_eo)(b) if batched else split_eo(b))
-    pb_e, pb_o = pack_spinor(b_e), pack_spinor(b_o)
+    with phases.scope(phases.GAUGE_LAYOUT):
+        u_e, u_o = split_eo_gauge(u)
+        upe, upo = pack_gauge(u_e), pack_gauge(u_o)
+    with phases.scope(phases.RHS_LAYOUT):
+        b_e, b_o = (jax.vmap(split_eo)(b) if batched else split_eo(b))
+        pb_e, pb_o = pack_spinor(b_e), pack_spinor(b_o)
     bspec = P(None, *psi_spec) if batched else psi_spec
     gput = lambda a: jax.device_put(a, NamedSharding(mesh, gauge_spec))
     sput = lambda a: jax.device_put(a, NamedSharding(mesh, bspec))
@@ -844,8 +866,8 @@ def _sharded_eo_solver(plan: SolverPlan, mass: float, tol: float,
         a_hat = lambda v: dist.schur_normal_op_halo(upe_l, upo_l, v, mass,
                                                     **skw)
         m_inv = site.solve
-        b_hat = pbe_l - d_eo(m_inv(pbo_l))
-        rhs = dhat_dag(b_hat)
+        with phases.scope(phases.SCHUR_RHS):
+            rhs = dhat_dag(pbe_l - d_eo(m_inv(pbo_l)))
         if plan.solver == "pipecg":
             x_e, st = solvers.pipecg(
                 a_hat, rhs, tol=tol, maxiter=maxiter,
@@ -855,7 +877,8 @@ def _sharded_eo_solver(plan: SolverPlan, mass: float, tol: float,
         else:
             x_e, st = solvers.cg(a_hat, rhs, tol=tol, maxiter=maxiter,
                                  dot=pdot, norm2=pnorm2, batched=batched)
-        x_o = m_inv(pbo_l - d_oe(x_e))
+        with phases.scope(phases.BACK_SUBSTITUTE):
+            x_o = m_inv(pbo_l - d_oe(x_e))
         return x_e, x_o, st
 
     stats_spec = solvers.SolveStats(P(), P(), P(), P(),
@@ -945,6 +968,7 @@ def _segmented_program(parts: solvers.LoopParts, post) -> LoopProgram:
     seg_cond = solvers.segment_cond(parts)
 
     @jax.jit
+    @phases.scoped(phases.KRYLOV)
     def step(carry, stop):
         out = jax.lax.while_loop(lambda c: seg_cond(c, stop),
                                  parts.body, carry)
@@ -969,9 +993,8 @@ def _loop_program_eo(plan, u, b, mass, *, tol, maxiter, dot, norm2,
     ctx = resolve(plan, u, mass, out_dtype=b.dtype)
     b_e, b_o = ctx.prepare(b)
     ops = ctx.ops
-    b_hat = b_e - ops.d_eo(ops.m_inv(b_o))
     a_hat = lambda v: ops.dhat_dag(ops.dhat(v))
-    rhs = ops.dhat_dag(b_hat)
+    rhs = schur_rhs(ops, b_e, b_o)
     if plan.solver == "pipecg":
         parts = solvers.pipecg_parts(
             a_hat, rhs, tol=tol, maxiter=maxiter,
@@ -986,7 +1009,7 @@ def _loop_program_eo(plan, u, b, mass, *, tol, maxiter, dot, norm2,
                                  **engine)
 
     def post(x_e, stats):
-        x_o = ops.m_inv(b_o - ops.d_oe(x_e))
+        x_o = back_substitute_odd(ops, b_o, x_e)
         return ctx.finish(x_e, x_o), stats
 
     return _segmented_program(parts, post)
@@ -1010,8 +1033,9 @@ def _loop_program_eo_mp(plan, u, b, mass, *, tol, maxiter, inner_tol,
         from repro.kernels.wilson_dslash import ops as wops
 
         high = b_e.dtype
-        u_e_lo = ops.u_e.astype(low_dtype)
-        u_o_lo = ops.u_o.astype(low_dtype)
+        with phases.scope(phases.GAUGE_LAYOUT):
+            u_e_lo = ops.u_e.astype(low_dtype)
+            u_o_lo = ops.u_o.astype(low_dtype)
         kkw = dict(twist=twist, bz=plan.bz, interpret=plan.interpret)
 
         def a_low(w):
@@ -1029,7 +1053,8 @@ def _loop_program_eo_mp(plan, u, b, mass, *, tol, maxiter, inner_tol,
             pair = complex_to_real_pair(w, dtype=low_dtype)
             return real_pair_to_complex(pair, dtype=w.dtype)
 
-        u_e_lo, u_o_lo = round_links(ops.u_e), round_links(ops.u_o)
+        with phases.scope(phases.GAUGE_LAYOUT):
+            u_e_lo, u_o_lo = round_links(ops.u_e), round_links(ops.u_o)
 
         def a_low(w):
             v = real_pair_to_complex(w, dtype=high)
@@ -1047,15 +1072,14 @@ def _loop_program_eo_mp(plan, u, b, mass, *, tol, maxiter, inner_tol,
     engine = {}
     if ctx.engine is not None:
         engine = dict(update=ctx.engine[0], xpay=ctx.engine[1])
-    b_hat = b_e - ops.d_eo(ops.m_inv(b_o))
     parts = solvers.mpcg_parts(
-        a_low, a_high, ops.dhat_dag(b_hat), tol=tol, inner_tol=inner_tol,
+        a_low, a_high, schur_rhs(ops, b_e, b_o), tol=tol, inner_tol=inner_tol,
         inner_maxiter=inner_maxiter, max_outer=max_outer,
         low_dtype=low_dtype, to_low=to_low, to_high=to_high,
         dot=dot, norm2=norm2, **engine)
 
     def post(x_e, stats):
-        x_o = ops.m_inv(b_o - ops.d_oe(x_e))
+        x_o = back_substitute_odd(ops, b_o, x_e)
         return ctx.finish(x_e, x_o), stats
 
     return _segmented_program(parts, post)
@@ -1069,8 +1093,7 @@ def _loop_program_full(plan, u, b, mass, *, tol, maxiter, inner_tol,
     from repro.kernels.wilson_dslash import ops as wops
 
     packed_in = layout == "packed"
-    up = u if packed_in else pack_gauge(u)
-    pp = b if packed_in else pack_spinor(b)
+    up, pp = _packed_fields(u, b, packed_in)
     m = float(mass)
     kw = dict(twist=_family_site(plan, mass).twist, bz=plan.bz,
               interpret=plan.interpret,
@@ -1110,7 +1133,7 @@ def _loop_program_full(plan, u, b, mass, *, tol, maxiter, inner_tol,
             x = x.astype(pp.dtype)
         if packed_in:
             return x, stats
-        return unpack_spinor(x, dtype=b.dtype), stats
+        return _unpacked(x, b.dtype), stats
 
     return _segmented_program(parts, post)
 
@@ -1243,10 +1266,7 @@ def _loop_program_eo_sharded(plan, u, b, mass, *, tol, maxiter,
 
     def finalize(carry):
         x_e, x_o, stats = finish_f(upe, upo, pb_e, pb_o, carry)
-        xe = unpack_spinor(x_e, dtype=b.dtype)
-        xo = unpack_spinor(x_o, dtype=b.dtype)
-        x = jax.vmap(merge_eo)(xe, xo) if batched else merge_eo(xe, xo)
-        return x, stats
+        return _merged(x_e, x_o, b.dtype, batched), stats
 
     return LoopProgram(start=start, step=step, counter=counter,
                        finalize=finalize)

@@ -49,6 +49,7 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import phases
 from repro.core.lattice import (exact_einsum, exact_matmul, field_dot,
                                 field_dot_batched, field_norm2,
                                 field_norm2_batched)
@@ -326,6 +327,13 @@ def cg_parts(op: Op, b: Array, x0: Array | None = None, *,
                      counter=lambda c: c[0])
 
 
+def _run(parts: LoopParts):
+    """Run a solver's parts to completion in one ``lax.while_loop``."""
+    return parts.finish(jax.lax.while_loop(parts.cond, parts.body,
+                                           parts.init))
+
+
+@phases.scoped(phases.KRYLOV)
 def cg(op: Op, b: Array, x0: Array | None = None, *,
        tol: float = 1e-8, maxiter: int = 1000,
        dot=field_dot, norm2=field_norm2,
@@ -356,10 +364,9 @@ def cg(op: Op, b: Array, x0: Array | None = None, *,
     injected engine must follow the batched contract (per-RHS ``rs`` from
     ``update``, gate argument on ``xpay``; see DESIGN.md §6).
     """
-    parts = cg_parts(op, b, x0, tol=tol, maxiter=maxiter, dot=dot,
-                     norm2=norm2, update=update, xpay=xpay, batched=batched)
-    return parts.finish(jax.lax.while_loop(parts.cond, parts.body,
-                                           parts.init))
+    return _run(cg_parts(op, b, x0, tol=tol, maxiter=maxiter, dot=dot,
+                         norm2=norm2, update=update, xpay=xpay,
+                         batched=batched))
 
 
 def cg_trace(op: Op, b: Array, *, iters: int,
@@ -491,11 +498,13 @@ def cgnr_eo(dhat: Op, dhat_dag: Op, d_eo: Op, d_oe: Op, m_inv: Op,
       ((x_e, x_o), SolveStats) — merge with ``lattice.merge_eo`` for the
       full-lattice solution.  ``iterations`` counts the half-size CG steps.
     """
-    b_hat = b_e - d_eo(m_inv(b_o))
-    x_e, stats = cg(lambda v: dhat_dag(dhat(v)), dhat_dag(b_hat), x0,
+    with phases.scope(phases.SCHUR_RHS):
+        rhs = dhat_dag(b_e - d_eo(m_inv(b_o)))
+    x_e, stats = cg(lambda v: dhat_dag(dhat(v)), rhs, x0,
                     tol=tol, maxiter=maxiter, dot=dot, norm2=norm2,
                     update=update, xpay=xpay, batched=batched)
-    x_o = m_inv(b_o - d_oe(x_e))
+    with phases.scope(phases.BACK_SUBSTITUTE):
+        x_o = m_inv(b_o - d_oe(x_e))
     return (x_e, x_o), stats
 
 
@@ -517,13 +526,15 @@ def mpcg_eo(a_low: Op, a_high: Op, dhat_dag: Op, d_eo: Op, d_oe: Op,
     ``mpcg``); complex half fields use the real-pair view helpers in
     :mod:`repro.core.lattice` since complex bf16 does not exist.
     """
-    b_hat = b_e - d_eo(m_inv(b_o))
-    x_e, stats = mpcg(a_low, a_high, dhat_dag(b_hat), tol=tol,
+    with phases.scope(phases.SCHUR_RHS):
+        rhs = dhat_dag(b_e - d_eo(m_inv(b_o)))
+    x_e, stats = mpcg(a_low, a_high, rhs, tol=tol,
                       inner_tol=inner_tol, inner_maxiter=inner_maxiter,
                       max_outer=max_outer, low_dtype=low_dtype,
                       to_low=to_low, to_high=to_high, dot=dot, norm2=norm2,
                       update=update, xpay=xpay, batched=batched)
-    x_o = m_inv(b_o - d_oe(x_e))
+    with phases.scope(phases.BACK_SUBSTITUTE):
+        x_o = m_inv(b_o - d_oe(x_e))
     return (x_e, x_o), stats
 
 
@@ -596,9 +607,9 @@ def mpcg_parts(op_low: Op, op_high: Op, b: Array, *,
         if batched:  # freeze converged systems: zero RHS -> inactive inner CG
             rhs = jnp.where(_bcast(rs > limit, r), r, jnp.zeros_like(r))
         r_low = to_low(rhs)
-        d, st = cg(op_low, r_low, tol=inner_tol, maxiter=inner_maxiter,
-                   dot=dot, norm2=norm2, update=update, xpay=xpay,
-                   batched=batched)
+        d, st = _run(cg_parts(op_low, r_low, tol=inner_tol,
+                              maxiter=inner_maxiter, dot=dot, norm2=norm2,
+                              update=update, xpay=xpay, batched=batched))
         broken = jnp.logical_or(broken, st.verdict == BREAKDOWN)
         x = x + to_high(d)
         r = b - op_high(x)                     # reliable update (true residual)
@@ -635,6 +646,7 @@ def mpcg_parts(op_low: Op, op_high: Op, b: Array, *,
                      counter=lambda c: c[1])
 
 
+@phases.scoped(phases.KRYLOV)
 def mpcg(op_low: Op, op_high: Op, b: Array, *,
          tol: float = 1e-6, inner_tol: float = 5e-2,
          inner_maxiter: int = 200, max_outer: int = 50,
@@ -648,13 +660,11 @@ def mpcg(op_low: Op, op_high: Op, b: Array, *,
     See :func:`mpcg_parts` for the full algorithm notes; this is exactly
     its parts run to completion in one ``lax.while_loop``.
     """
-    parts = mpcg_parts(op_low, op_high, b, tol=tol, inner_tol=inner_tol,
-                       inner_maxiter=inner_maxiter, max_outer=max_outer,
-                       low_dtype=low_dtype, to_low=to_low, to_high=to_high,
-                       dot=dot, norm2=norm2, update=update, xpay=xpay,
-                       batched=batched)
-    return parts.finish(jax.lax.while_loop(parts.cond, parts.body,
-                                           parts.init))
+    return _run(mpcg_parts(op_low, op_high, b, tol=tol, inner_tol=inner_tol,
+                           inner_maxiter=inner_maxiter, max_outer=max_outer,
+                           low_dtype=low_dtype, to_low=to_low,
+                           to_high=to_high, dot=dot, norm2=norm2,
+                           update=update, xpay=xpay, batched=batched))
 
 
 # ---------------------------------------------------------------------------
@@ -790,6 +800,7 @@ def pipecg_parts(op: Op, b: Array, *, tol: float = 1e-8,
                      counter=lambda c: c[0])
 
 
+@phases.scoped(phases.KRYLOV)
 def pipecg(op: Op, b: Array, *, tol: float = 1e-8, maxiter: int = 1000,
            residual_replacement_every: int = 25,
            dot=field_dot, norm2=field_norm2, fused_dots=None,
@@ -799,12 +810,10 @@ def pipecg(op: Op, b: Array, *, tol: float = 1e-8, maxiter: int = 1000,
     See :func:`pipecg_parts` for the full algorithm notes; this is exactly
     its parts run to completion in one ``lax.while_loop``.
     """
-    parts = pipecg_parts(
+    return _run(pipecg_parts(
         op, b, tol=tol, maxiter=maxiter,
         residual_replacement_every=residual_replacement_every,
-        dot=dot, norm2=norm2, fused_dots=fused_dots, batched=batched)
-    return parts.finish(jax.lax.while_loop(parts.cond, parts.body,
-                                           parts.init))
+        dot=dot, norm2=norm2, fused_dots=fused_dots, batched=batched))
 
 
 # ---------------------------------------------------------------------------
@@ -918,6 +927,7 @@ def _gram_psolve(g: Array, rhs: Array, rcond: float = 1e-7) -> Array:
                         * exact_matmul(evecs.conj().T, rhs))
 
 
+@phases.scoped(phases.KRYLOV)
 def blockcg(op: Op, b: Array, x0: Array | None = None, *,
             tol: float = 1e-8, maxiter: int = 1000,
             norm2=field_norm2_batched) -> tuple[Array, SolveStats]:
@@ -1036,6 +1046,7 @@ class DeflationBasis(NamedTuple):
         return self.w.shape[0]
 
 
+@phases.scoped(phases.KRYLOV)
 def cg_harvest(op: Op, b: Array, *, tol: float = 1e-8, maxiter: int = 1000,
                m_max: int = 48, dot=field_dot, norm2=field_norm2,
                ) -> tuple[Array, SolveStats, tuple[Array, Array, Array]]:

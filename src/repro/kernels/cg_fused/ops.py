@@ -18,6 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.core import phases
 from repro.kernels.cg_fused.kernel import (LANE, cg_update_batched_pallas,
                                            cg_update_pallas,
                                            cg_xpay_batched_pallas,
@@ -55,6 +56,7 @@ def _to_stream_batched(v: jax.Array):
     return flat.reshape(nb, rows, LANE), pad
 
 
+@phases.scoped(phases.CG_UPDATE)
 @functools.partial(jax.jit, static_argnames=("interpret", "use_pallas"))
 def cg_update(alpha, x, r, p, ap, *, interpret: bool | None = None,
               use_pallas: bool = True):
@@ -74,6 +76,7 @@ def cg_update(alpha, x, r, p, ap, *, interpret: bool | None = None,
             ro.reshape(-1)[:n].reshape(shape), rs)
 
 
+@phases.scoped(phases.CG_XPAY)
 @functools.partial(jax.jit, static_argnames=("interpret", "use_pallas"))
 def cg_xpay(beta, r, p, *, interpret: bool | None = None,
             use_pallas: bool = True):
@@ -89,6 +92,7 @@ def cg_xpay(beta, r, p, *, interpret: bool | None = None,
     return po.reshape(-1)[:p.size].reshape(shape)
 
 
+@phases.scoped(phases.CG_UPDATE)
 @functools.partial(jax.jit, static_argnames=("interpret", "use_pallas"))
 def cg_update_batched(alpha, x, r, p, ap, *, interpret: bool | None = None,
                       use_pallas: bool = True):
@@ -113,6 +117,7 @@ def cg_update_batched(alpha, x, r, p, ap, *, interpret: bool | None = None,
             ro.reshape(nb, -1)[:, :per].reshape(shape), rs)
 
 
+@phases.scoped(phases.CG_XPAY)
 @functools.partial(jax.jit, static_argnames=("interpret", "use_pallas"))
 def cg_xpay_batched(beta, r, p, gate, *, interpret: bool | None = None,
                     use_pallas: bool = True):

@@ -32,6 +32,7 @@ import functools
 
 import jax
 
+from repro.core import phases
 from repro.core.operators import apply_igamma5_packed, schur_launch_coeffs
 from repro.core.wilson import apply_gamma5_packed, dslash_packed
 from repro.kernels.wilson_dslash.kernel import (dslash_eo_pallas,
@@ -45,6 +46,7 @@ _STATIC = ("mass", "twist", "bz", "interpret", "use_pallas")
 _STATIC_G5 = _STATIC + ("gamma5_in", "gamma5_out")
 
 
+@phases.scoped(phases.DSLASH)
 @functools.partial(jax.jit, static_argnames=_STATIC_G5)
 def dslash(up: jax.Array, pp: jax.Array, mass: float, *,
            twist: float = 0.0, bz: int | None = None,
@@ -102,6 +104,7 @@ def normal_op(up: jax.Array, pp: jax.Array, mass: float, *,
 _STATIC_EO = ("bz", "interpret", "use_pallas", "gamma5_in", "gamma5_out")
 
 
+@phases.scoped(phases.HOP_EO)
 @functools.partial(jax.jit, static_argnames=_STATIC_EO)
 def dslash_eo(u_e: jax.Array, u_o: jax.Array, pp_o: jax.Array, *,
               bz: int | None = None, interpret: bool | None = None,
@@ -120,6 +123,7 @@ def dslash_eo(u_e: jax.Array, u_o: jax.Array, pp_o: jax.Array, *,
                             gamma5_in=gamma5_in, gamma5_out=gamma5_out)
 
 
+@phases.scoped(phases.HOP_OE)
 @functools.partial(jax.jit, static_argnames=_STATIC_EO)
 def dslash_oe(u_e: jax.Array, u_o: jax.Array, pp_e: jax.Array, *,
               bz: int | None = None, interpret: bool | None = None,
@@ -186,6 +190,7 @@ def hop_block(u_e: jax.Array, u_o: jax.Array, pp: jax.Array, *,
 _STATIC_SCHUR = ("mass", "twist", "bz", "interpret", "use_pallas", "dagger")
 
 
+@phases.scoped(phases.SCHUR)
 @functools.partial(jax.jit, static_argnames=_STATIC_SCHUR)
 def schur_op(u_e: jax.Array, u_o: jax.Array, pp_e: jax.Array, mass: float, *,
              twist: float = 0.0, bz: int | None = None,

@@ -91,6 +91,12 @@ Array = jax.Array
 _CLOSE = object()
 
 
+def _span(name: str):
+    """A host span on the profiler's clock, which the device trace shares.
+    Spans nest per thread, so none may stay open across an ``await``."""
+    return jax.profiler.TraceAnnotation(f"repro.serve.{name}")
+
+
 @dataclasses.dataclass(frozen=True)
 class SolveRequest:
     """One client solve: which operator, which gauge field, which RHS.
@@ -341,7 +347,8 @@ class SolverServer:
                 f"{sorted(self._gauges)}")
         self._plan_for(request, None)  # validate family/mu NOW, not in batch
         if self.admission_validation:
-            self._admit(request)
+            with _span("admit"):
+                self._admit(request)
         loop = asyncio.get_running_loop()
         key = self._coalesce_key(request)
         queue = self._queues.get(key)
@@ -472,17 +479,19 @@ class SolverServer:
                 if basis is not None
                 else self.plans.get(plan, mass, self.maxiter))
             u = self._gauges[str(first.gauge_id)]
-            b = pad_batch([r.rhs for r in requests], rung)
-            tol = pad_tols([r.tol for r in requests], rung)
+            with _span("pad"):
+                b = pad_batch([r.rhs for r in requests], rung)
+                tol = pad_tols([r.tol for r in requests], rung)
             # the containment re-solve IS the clean retry of the transient
             # fault model: the injector only sees primary dispatches
             injector = None if retried else self.fault_injector
 
             def run():
-                uu, bb = (u, b) if injector is None else injector(u, b)
-                x, stats = (fn(uu, bb, tol) if basis is None
-                            else fn(uu, bb, tol, basis.w, basis.gram))
-                jax.block_until_ready(x)
+                with _span("dispatch"):
+                    uu, bb = (u, b) if injector is None else injector(u, b)
+                    x, stats = (fn(uu, bb, tol) if basis is None
+                                else fn(uu, bb, tol, basis.w, basis.gram))
+                    jax.block_until_ready(x)
                 return x, stats
 
             x, stats = await loop.run_in_executor(self._exec, run)
@@ -518,43 +527,44 @@ class SolverServer:
         self._served += len(batch)
         if cache_hit:
             self._served_cache_hits += len(batch)
-        rhs_iters = jax.device_get(stats.rhs_iterations)
-        converged = jax.device_get(stats.converged)
-        res2 = jax.device_get(stats.residual_norm2)
-        verdicts = jax.device_get(stats.verdict)
-        verified = jax.device_get(stats.verified)
-        true_res2 = jax.device_get(stats.true_residual_norm2)
         retry: list[_Pending] = []
-        for i, p in enumerate(batch):
-            verdict = verdict_name(verdicts[i])
-            ok = bool(converged[i]) and bool(verified[i])
-            if not ok:
-                if not retried:
-                    # containment: one clean INDIVIDUAL re-solve — rescues
-                    # a healthy lane hit by a transient fault or by batch
-                    # effects of a poisoned neighbour; a genuinely poisoned
-                    # request fails the retry too (classified, terminal)
-                    retry.append(p)
+        with _span("results"):
+            rhs_iters = jax.device_get(stats.rhs_iterations)
+            converged = jax.device_get(stats.converged)
+            res2 = jax.device_get(stats.residual_norm2)
+            verdicts = jax.device_get(stats.verdict)
+            verified = jax.device_get(stats.verified)
+            true_res2 = jax.device_get(stats.true_residual_norm2)
+            for i, p in enumerate(batch):
+                verdict = verdict_name(verdicts[i])
+                ok = bool(converged[i]) and bool(verified[i])
+                if not ok:
+                    if not retried:
+                        # containment: one clean INDIVIDUAL re-solve — rescues
+                        # a healthy lane hit by a transient fault or by batch
+                        # effects of a poisoned neighbour; a genuinely poisoned
+                        # request fails the retry too (classified, terminal)
+                        retry.append(p)
+                    else:
+                        self._fail(p, RequestFailed(
+                            f"request failed verification (verdict={verdict}, "
+                            f"true ‖r‖²={float(true_res2[i]):.3e})",
+                            verdict=verdict, retried=retried), verdict=verdict)
                 else:
-                    self._fail(p, RequestFailed(
-                        f"request failed verification (verdict={verdict}, "
-                        f"true ‖r‖²={float(true_res2[i]):.3e})",
-                        verdict=verdict, retried=retried), verdict=verdict)
-            else:
-                if retried:
-                    self._lane_retries_rescued += 1
-                st = RequestStats(
-                    queue_s=t_dispatch - p.t_enqueue, solve_s=solve_s,
-                    batch_size=len(batch), padded_to=rung,
-                    iterations=int(rhs_iters[i]),
-                    converged=bool(converged[i]),
-                    residual_norm2=float(res2[i]), plan_cache_hit=cache_hit,
-                    verdict=verdict, verified=bool(verified[i]),
-                    true_residual_norm2=float(true_res2[i]), retried=retried,
-                    deflation_cache_hit=basis is not None)
-                self._journal_complete(p, "ok")
-                if not p.future.done():
-                    p.future.set_result(SolveResult(x=x[i], stats=st))
+                    if retried:
+                        self._lane_retries_rescued += 1
+                    st = RequestStats(
+                        queue_s=t_dispatch - p.t_enqueue, solve_s=solve_s,
+                        batch_size=len(batch), padded_to=rung,
+                        iterations=int(rhs_iters[i]),
+                        converged=bool(converged[i]),
+                        residual_norm2=float(res2[i]), plan_cache_hit=cache_hit,
+                        verdict=verdict, verified=bool(verified[i]),
+                        true_residual_norm2=float(true_res2[i]), retried=retried,
+                        deflation_cache_hit=basis is not None)
+                    self._journal_complete(p, "ok")
+                    if not p.future.done():
+                        p.future.set_result(SolveResult(x=x[i], stats=st))
         for p in retry:
             self._lane_retries += 1
             await self._solve_batch([p], retried=True)

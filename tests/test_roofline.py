@@ -2,9 +2,9 @@
 JSONs: closed-form values, monotonicity, and input validation.
 
 ``benchmarks/roofline.dslash_intensity`` is the denominator of the
-achieved-vs-roofline column gated by ``check_solver_regression.py
---perf`` — a wrong model silently re-scales every committed bandwidth
-fraction, so the closed form is pinned here:
+achieved-vs-roofline ``bw_fraction`` column of the bench JSONs — a wrong
+model silently re-scales every reported bandwidth fraction, so the
+closed form is pinned here:
 
     bytes/site/RHS = (144 / N + 48) · dtype_bytes
     flops/site     = 1320

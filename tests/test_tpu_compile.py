@@ -9,7 +9,9 @@ fixture, never at import: only one process at a time may load the TPU
 library, and every test worker imports this file.
 """
 
+import contextlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -19,6 +21,8 @@ from jax.sharding import SingleDeviceSharding
 import repro.kernels.cg_fused.kernel as cg_kernel
 import repro.kernels.cg_fused.ops as cg_ops
 import repro.kernels.wilson_dslash.kernel as dslash_kernel
+from repro.core import phases
+from repro.core import plan as plan_mod
 from repro.kernels.wilson_dslash.ops import schur_normal_op
 
 T, Z, Y, X = 48, 24, 24, 24   # ETMC A40.24-sized lattice
@@ -43,24 +47,31 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture
-def mosaic(monkeypatch):
+@contextlib.contextmanager
+def _mosaic_lowering():
     """Lower every kernel with Mosaic and keep the compile cache off: a
     compile for a described chip can be written to the cache but not read
     back without one."""
-    monkeypatch.setattr(dslash_kernel, "resolve_lowering",
-                        lambda interpret: "mosaic")
-    monkeypatch.setattr(cg_ops, "resolve_lowering",
-                        lambda interpret: "mosaic")
-    monkeypatch.setattr(cg_kernel, "resolve_interpret",
-                        lambda interpret: False)
     from jax.experimental.compilation_cache import compilation_cache
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(dslash_kernel, "resolve_lowering",
+                   lambda interpret: "mosaic")
+        mp.setattr(cg_ops, "resolve_lowering", lambda interpret: "mosaic")
+        mp.setattr(cg_kernel, "resolve_interpret", lambda interpret: False)
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            yield mp
+        finally:
+            jax.config.update("jax_enable_compilation_cache", was)
+            compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def mosaic():
+    with _mosaic_lowering():
+        yield
 
 
 def _half_fields(one_chip):
@@ -115,3 +126,104 @@ def test_fused_cg_vector_kernels_compile(mosaic, one_chip):
     _compile(lambda a, x, r, q, ap: cg_ops.cg_update(a, x, r, q, ap),
              scalar, p, p, p, p)
     _compile(lambda b, r, q: cg_ops.cg_xpay(b, r, q), scalar, p, p)
+
+
+# ---------------------------------------------------------------------------
+# The solve's phase scopes (repro.core.phases) in the compiled program
+# ---------------------------------------------------------------------------
+
+SMALL = (8, 4, 4, 8)    # T, Z, Y, X
+# the custom calls of the eo-schur Pallas CGNR solve, as the device trace
+# names them: the benchmark's kernel metrics match these names
+KERNELS = {None: {"schur_op", "dslash_eo", "dslash_oe", "cg_update",
+                  "cg_xpay"},
+           2: {"schur_op", "dslash_eo", "dslash_oe", "cg_update_batched",
+               "cg_xpay_batched"}}
+# instructions that carry no op of the program: the compiler's own
+EXEMPT = {"parameter", "constant", "tuple", "get-tuple-element"}
+
+
+@contextlib.contextmanager
+def _no_scope(name):
+    yield
+
+
+@pytest.fixture(scope="module")
+def solve_text(one_chip):
+    """(nrhs, scoped) -> ``as_text()`` of the eo-schur Pallas CGNR solve
+    compiled for the described chip at a small lattice; ``scoped=False``
+    compiles it with every phase scope a no-op."""
+    texts = {}
+
+    def get(nrhs, scoped=True):
+        if (nrhs, scoped) not in texts:
+            spec = lambda shape: jax.ShapeDtypeStruct(
+                shape, jnp.complex64, sharding=one_chip)
+            u = spec((4,) + SMALL + (3, 3))
+            b = spec(((nrhs,) if nrhs else ()) + SMALL + (4, 3))
+            plan = plan_mod.SolverPlan(operator="eo-schur", backend="pallas",
+                                       solver="cgnr", nrhs=nrhs)
+            fn = jax.jit(lambda u, b: plan_mod.solve(plan, u, b, 0.1,
+                                                     tol=1e-6, maxiter=100))
+            with _mosaic_lowering() as mp:
+                if not scoped:
+                    mp.setattr(phases, "scope", _no_scope)
+                texts[nrhs, scoped] = fn.lower(u, b).compile().as_text()
+        return texts[nrhs, scoped]
+
+    return get
+
+
+def _device_ops(text):
+    """(name, opcode, op_name or None) of each instruction the device runs
+    as an op: those of the computations no fusion or reduction calls."""
+    inner = set(re.findall(r"(?:calls|to_apply)=%([\w.-]+)", text))
+    comp = None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.-]+) .*\{$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        m = re.match(r"\s+(?:ROOT )?%([\w.-]+) = (?:\(.*?\)|\S+) "
+                     r"([\w-]+)\(", line)
+        if m and comp not in inner:
+            op = re.search(r'op_name="([^"]*)"', line)
+            yield m.group(1), m.group(2), op.group(1) if op else None
+
+
+def _strip(text):
+    """The module without its debug tables and metadata."""
+    lines = text.splitlines()
+    start = next(i for i, line in enumerate(lines)
+                 if line.startswith(("%", "ENTRY")))
+    return re.sub(r",? metadata=\{[^{}]*\}", "", "\n".join(lines[start:]))
+
+
+@pytest.mark.parametrize("nrhs", [None, 2], ids=["single", "batched"])
+def test_every_op_of_the_solve_is_in_one_phase(solve_text, nrhs):
+    """Each op the program traced (its op_name is a path) names exactly one
+    ``solve.*`` phase.  The compiler's own copies, bitcasts and splits of
+    a complex parameter carry no such path and are exempt."""
+    seen, stray = set(), []
+    for name, opcode, op_name in _device_ops(solve_text(nrhs)):
+        if opcode in EXEMPT or op_name is None or "/" not in op_name:
+            continue
+        found = {m for part in op_name.split(";")
+                 for m in re.findall(r"(?:^|/)(solve\.[a-z_]+)(?=/|$)", part)}
+        seen |= found
+        if len(found) != 1:
+            stray.append((name, op_name))
+    assert stray == []
+    assert seen == set(phases.SOLVE_PHASES)
+
+
+@pytest.mark.parametrize("nrhs", [None, 2], ids=["single", "batched"])
+def test_kernel_launches_keep_their_names(solve_text, nrhs):
+    names = re.findall(r'^\s+(?:ROOT )?%([\w.-]+) = .*custom_call_target='
+                       r'"tpu_custom_call"', solve_text(nrhs), re.M)
+    assert {re.sub(r"\.\d+$", "", n) for n in names} == KERNELS[nrhs]
+
+
+@pytest.mark.parametrize("nrhs", [None, 2], ids=["single", "batched"])
+def test_scopes_change_only_metadata(solve_text, nrhs):
+    assert _strip(solve_text(nrhs)) == _strip(solve_text(nrhs, False))
