@@ -19,10 +19,12 @@ from repro.core.lattice import (LatticeShape, complex_to_real_pair,
                                 eo_row_offset, field_dot, field_dot_batched,
                                 field_norm2, field_norm2_batched,
                                 merge_eo, merge_eo_gauge, pack_gauge,
+                                pack_half_gauge, pack_half_spinor,
                                 pack_spinor, parity_masks, random_gauge,
                                 random_spinor, real_pair_to_complex,
                                 split_eo, split_eo_gauge, unit_gauge,
-                                unpack_gauge, unpack_spinor)
+                                unpack_gauge, unpack_half_gauge,
+                                unpack_half_spinor, unpack_spinor)
 from repro.core.operators import (LatticeOperator, SiteTerm, dslash_g,
                                   dslash_dagger_g, get_operator,
                                   normal_op_g, operator_names,

@@ -33,6 +33,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import phases
+from repro.core.lattice import half_lanes, half_rows
 from repro.core.operators import apply_igamma5_packed, schur_launch_coeffs
 from repro.core.wilson import (apply_gamma5_packed, dslash_packed,
                                hop_term_packed)
@@ -129,29 +130,60 @@ def normal_op_halo(up, pp, mass, sharded, r: float = 1.0,
 # ---------------------------------------------------------------------------
 #
 # The parity hop blocks D_eo / D_oe only roll the UNCOMPRESSED axes
-# (T, Z, Y) — the x-direction hops stay inside a row (the lane axis, never
-# sharded) — so their halo structure is identical to the full-lattice
-# stencil above: evaluate the bulk with local periodic wrap, then correct
-# the two boundary planes of every sharded direction with
-# `collective_permute`d neighbour planes.  The correction hop for a
-# t/z/y direction on a parity-compressed half field is the SAME
-# ``hop_term_packed`` used by the full-lattice fix-ups: this is the
-# paper's layering argument made concrete — the data-transport layer is
-# untouched while the operator underneath swapped from full to parity.
+# (T, Z, Y) — the x-direction hops stay inside a row (never sharded) — so
+# their halo structure is identical to the full-lattice stencil above:
+# evaluate the bulk with local periodic wrap, then correct the two
+# boundary planes of every sharded direction with `collective_permute`d
+# neighbour planes.  The correction hop for a t/z/y direction on a
+# parity-compressed half field is the SAME ``hop_term_packed`` used by the
+# full-lattice fix-ups: this is the paper's layering argument made
+# concrete — the data-transport layer is untouched while the operator
+# underneath swapped from full to parity.
+#
+# Packed half fields are (…, T, 24, Z, Y*Xh) (repro.core.lattice): a T
+# or Z boundary plane is one index of its own axis, a Y boundary plane the
+# first or last Xh lanes.  Each plane is taken in the row view
+# ``half_rows`` ((…, T, Z, Y, 24, Xh), the layout ``hop_term_packed``
+# computes on) and its correction goes back through ``half_lanes``:
+# plane-sized transposes only.
 #
 # Requirement: every sharded LOCAL extent must be even.  Shard origins
 # are then even too, so each device's local row parity equals the global
 # row parity and the (local) bulk kernels compute the right projections.
 #
-# A batched RHS axis (N, T, Z, Y, 24, Xh) rides in front and is never
+# A batched RHS axis (N, T, 24, Z, Y*Xh) rides in front and is never
 # sharded: the spinor boundary planes carry the batch, but the GAUGE
 # boundary planes don't — each direction's link halo is exchanged once
 # per plane regardless of N.
 
 
 def _g5(p: jax.Array) -> jax.Array:
-    """gamma5 on a (possibly batched) plane of a packed half field."""
+    """gamma5 on a (possibly batched) row-view plane of a half field."""
     return apply_gamma5_packed(p)
+
+
+def _edge_index(arr: jax.Array, mu: int, idx: int, xh: int):
+    """Index of the boundary plane ``idx`` (0 or -1) of lattice axis
+    ``mu`` in a packed half field (…, T, C, Z, Y*Xh)."""
+    sl = [slice(None)] * arr.ndim
+    if mu == 2:    # Y: the first or last row of Xh lanes
+        sl[-1] = slice(0, xh) if idx == 0 else slice(-xh, None)
+    else:          # T (axis -4) or Z (axis -2)
+        sl[-4 if mu == 0 else -2] = (slice(0, 1) if idx == 0
+                                     else slice(-1, None))
+    return tuple(sl)
+
+
+def _edge(arr: jax.Array, mu: int, idx: int, xh: int) -> jax.Array:
+    """A boundary plane of a packed half field, in the row view."""
+    return half_rows(arr[_edge_index(arr, mu, idx, xh)], xh)
+
+
+def _add_edge(arr: jax.Array, mu: int, idx: int, delta: jax.Array,
+              xh: int) -> jax.Array:
+    """Add a row-view correction to a boundary plane of a half field."""
+    return arr.at[_edge_index(arr, mu, idx, xh)].add(
+        half_lanes(delta).astype(arr.dtype))
 
 
 def _hop_plane(u_plane: jax.Array, psi_plane: jax.Array, mu: int,
@@ -166,7 +198,8 @@ def _hop_plane(u_plane: jax.Array, psi_plane: jax.Array, mu: int,
 
 def parity_hop_halo(which: str, u_e: jax.Array, u_o: jax.Array,
                     pp: jax.Array, sharded: Mapping[int, tuple[str, int]], *,
-                    use_pallas: bool = False, gamma5_in: bool = False,
+                    xh: int, use_pallas: bool = False,
+                    gamma5_in: bool = False,
                     gamma5_out: bool = False, psi_acc: jax.Array | None = None,
                     acc_coeff: float = 0.0, hop_coeff: float = 1.0,
                     acc_twist: float = 0.0, hop_twist: float = 0.0,
@@ -183,34 +216,33 @@ def parity_hop_halo(which: str, u_e: jax.Array, u_o: jax.Array,
     site-term twists — are applied to the correction PLANES only
     (plane-sized work), mirroring the kernels' trace-time folding: no
     standalone full-field γ5/twist pass exists on this path for any
-    operator family.
+    operator family.  ``xh`` is the compressed X extent X/2.
     """
     # local import: repro.core is imported by the kernels package, so a
     # module-level import here would be circular.
     from repro.kernels.wilson_dslash import ops as wops
 
     with phases.scope(phases.HOP_EO if which == "eo" else phases.HOP_OE):
-        out = wops.hop_block(u_e, u_o, pp, which=which, gamma5_in=gamma5_in,
+        out = wops.hop_block(u_e, u_o, pp, which=which, xh=xh,
+                             gamma5_in=gamma5_in,
                              gamma5_out=gamma5_out, psi_acc=psi_acc,
                              acc_coeff=acc_coeff, hop_coeff=hop_coeff,
                              acc_twist=acc_twist, hop_twist=hop_twist,
                              use_pallas=use_pallas, bz=bz,
                              interpret=interpret)
     u_out, u_nbr = (u_e, u_o) if which == "eo" else (u_o, u_e)
-    batch = pp.ndim - 5  # 0 or 1 leading RHS-batch axes
     hc = jnp.asarray(hop_coeff, jnp.float32)
     for mu, (ax, n) in sorted(sharded.items()):
         if n == 1:
             continue
         fwd = [(i, (i + 1) % n) for i in range(n)]  # recv from prev rank
         bwd = [(i, (i - 1) % n) for i in range(n)]  # recv from next rank
-        pax = mu + batch
-        first = _take(pp, pax, 0)
-        last = _take(pp, pax, -1)
+        first = _edge(pp, mu, 0, xh)
+        last = _edge(pp, mu, -1, xh)
         if gamma5_in:  # fold γ5 into the plane, exactly like the kernels
             first, last = _g5(first), _g5(last)
-        u_out_last = _take(u_out[mu], mu, -1)
-        u_nbr_last = _take(u_nbr[mu], mu, -1)
+        u_out_last = _edge(u_out[mu], mu, -1, xh)
+        u_nbr_last = _edge(u_nbr[mu], mu, -1, xh)
 
         psi_prev = lax.ppermute(last, ax, fwd)    # ψ at my (axis)-1 edge
         u_prev = lax.ppermute(u_nbr_last, ax, fwd)  # U_mu at that edge
@@ -234,14 +266,15 @@ def parity_hop_halo(which: str, u_e: jax.Array, u_o: jax.Array,
             delta_f = hc * delta_f + ht * apply_igamma5_packed(delta_f)
         else:
             delta_b, delta_f = hc * delta_b, hc * delta_f
-        out = _add_at(out, pax, 0, delta_b)
-        out = _add_at(out, pax, -1, delta_f)
+        out = _add_edge(out, mu, 0, delta_b, xh)
+        out = _add_edge(out, mu, -1, delta_f, xh)
     return out
 
 
-def schur_op_halo(u_e, u_o, pp_e, mass, sharded, *, use_pallas: bool = False,
-                  twist: float = 0.0, dagger: bool = False,
-                  bz: int | None = None, interpret: bool | None = None):
+def schur_op_halo(u_e, u_o, pp_e, mass, sharded, *, xh: int,
+                  use_pallas: bool = False, twist: float = 0.0,
+                  dagger: bool = False, bz: int | None = None,
+                  interpret: bool | None = None):
     """Sharded Schur complement D_hat ψ = S ψ - D_eo S^-1 D_oe ψ with the
     registry site term S = (mass+4) + i·twist·γ5 (Wilson: twist = 0).
 
@@ -251,42 +284,36 @@ def schur_op_halo(u_e, u_o, pp_e, mass, sharded, *, use_pallas: bool = False,
     and their ppermutes, which XLA overlaps with the bulk stencils.
     """
     m = float(mass) + 4.0
+    kw = dict(xh=xh, use_pallas=use_pallas, bz=bz, interpret=interpret)
     if twist == 0.0:
         tmp_o = parity_hop_halo("oe", u_e, u_o, pp_e, sharded,
-                                use_pallas=use_pallas, gamma5_in=dagger,
-                                bz=bz, interpret=interpret)
+                                gamma5_in=dagger, **kw)
         return parity_hop_halo("eo", u_e, u_o, tmp_o, sharded,
-                               use_pallas=use_pallas, gamma5_out=dagger,
-                               psi_acc=pp_e, acc_coeff=m,
-                               hop_coeff=-1.0 / m,
-                               bz=bz, interpret=interpret)
+                               gamma5_out=dagger, psi_acc=pp_e, acc_coeff=m,
+                               hop_coeff=-1.0 / m, **kw)
     # twisted: the same two-launch split as the single-device kernels —
     # the sign algebra has ONE home, operators.schur_launch_coeffs
     # (S(∓tw)^-1 into the first block's epilogue, S(±tw) into the
     # second block's accumulator; dagger = γ5 D_hat(-tw) γ5)
     h1c, h1t, acc, acct = schur_launch_coeffs(m, twist, dagger)
-    tmp_o = parity_hop_halo("oe", u_e, u_o, pp_e, sharded,
-                            use_pallas=use_pallas, gamma5_in=dagger,
-                            hop_coeff=h1c, hop_twist=h1t,
-                            bz=bz, interpret=interpret)
-    return parity_hop_halo("eo", u_e, u_o, tmp_o, sharded,
-                           use_pallas=use_pallas, gamma5_out=dagger,
+    tmp_o = parity_hop_halo("oe", u_e, u_o, pp_e, sharded, gamma5_in=dagger,
+                            hop_coeff=h1c, hop_twist=h1t, **kw)
+    return parity_hop_halo("eo", u_e, u_o, tmp_o, sharded, gamma5_out=dagger,
                            psi_acc=pp_e, acc_coeff=acc, acc_twist=acct,
-                           hop_coeff=-1.0, bz=bz, interpret=interpret)
+                           hop_coeff=-1.0, **kw)
 
 
-def schur_normal_op_halo(u_e, u_o, pp_e, mass, sharded, *,
+def schur_normal_op_halo(u_e, u_o, pp_e, mass, sharded, *, xh: int,
                          use_pallas: bool = False, twist: float = 0.0,
                          bz: int | None = None,
                          interpret: bool | None = None):
     """A_hat = D_hat^dag D_hat on local shards — four hop blocks, zero
     standalone full-field γ5/axpy/twist passes, halo corrections per
     block, for every registered operator family."""
-    w = schur_op_halo(u_e, u_o, pp_e, mass, sharded, use_pallas=use_pallas,
-                      twist=twist, bz=bz, interpret=interpret)
-    return schur_op_halo(u_e, u_o, w, mass, sharded, use_pallas=use_pallas,
-                         twist=twist, dagger=True, bz=bz,
-                         interpret=interpret)
+    kw = dict(xh=xh, use_pallas=use_pallas, twist=twist, bz=bz,
+              interpret=interpret)
+    w = schur_op_halo(u_e, u_o, pp_e, mass, sharded, **kw)
+    return schur_op_halo(u_e, u_o, w, mass, sharded, dagger=True, **kw)
 
 
 # ---------------------------------------------------------------------------
@@ -309,6 +336,20 @@ def lattice_specs(mesh: Mesh, axis_map: Mapping[int, str] | None = None):
     psi_spec = P(*spin)
     gauge_spec = P(None, *spin)
     return psi_spec, gauge_spec, sharded
+
+
+def half_lattice_specs(mesh: Mesh, axis_map: Mapping[int, str] | None = None):
+    """(psi_spec, gauge_spec, sharded) for packed HALF fields.
+
+    Half fields are (T, 24, Z, Y*Xh) and per-parity links
+    (4, T, 18, Z, Y*Xh): T and Z shard on their own axes, and Y on the
+    lanes, whose y-major order gives each shard a contiguous run of rows.
+    """
+    _, _, sharded = lattice_specs(mesh, axis_map)
+    spin = [None] * 4
+    for mu, (name, _) in sharded.items():
+        spin[(0, 2, 3)[mu]] = name
+    return P(*spin), P(None, *spin), sharded
 
 
 def make_psum_dots(mesh: Mesh, batched: bool = False):

@@ -30,9 +30,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import phases, solvers
-from repro.core.lattice import (field_dot, field_norm2, merge_eo, pack_gauge,
-                                pack_spinor, split_eo, split_eo_gauge,
-                                unpack_spinor)
+from repro.core.lattice import (field_dot, field_norm2, merge_eo,
+                                pack_half_gauge, pack_half_spinor, split_eo,
+                                split_eo_gauge, unpack_half_spinor)
 from repro.core.operators import (SiteTerm, schur_dagger_g, schur_op_g)
 from repro.core.wilson import dslash_eo, dslash_oe
 
@@ -79,10 +79,11 @@ def eo_operators_packed(u: Array, mass, r: float = 1.0, *,
                         use_pallas: bool = True) -> EOOperators:
     """The Schur-system blocks on PACKED half fields, Pallas fast path.
 
-    The returned callables act on packed (T, Z, Y, 24, Xh) real half
-    fields — or (N, T, Z, Y, 24, Xh) RHS batches: every block is
-    rank-polymorphic, and the Pallas kernels amortize each gauge-plane
-    fetch across the whole batch (see DESIGN.md §6).
+    The returned callables act on packed (T, 24, Z, Y*Xh) real half
+    fields (:func:`repro.core.lattice.pack_half_spinor`) — or
+    (N, T, 24, Z, Y*Xh) RHS batches: every block is rank-polymorphic, and
+    the Pallas kernels amortize each gauge-plane fetch across the whole
+    batch (see DESIGN.md §6).
 
     Supported-parameter matrix (packed path, ``use_pallas`` either way —
     the packed references round-trip through the same spin-projection
@@ -119,9 +120,10 @@ def eo_operators_packed(u: Array, mass, r: float = 1.0, *,
 
     with phases.scope(phases.GAUGE_LAYOUT):
         u_e, u_o = split_eo_gauge(u)
-        upe, upo = pack_gauge(u_e), pack_gauge(u_o)
+        upe, upo = pack_half_gauge(u_e), pack_half_gauge(u_o)
     site = SiteTerm(mass + 4.0 * r, twist)
-    kw = dict(bz=bz, interpret=interpret, use_pallas=use_pallas)
+    kw = dict(xh=u.shape[4] // 2, bz=bz, interpret=interpret,
+              use_pallas=use_pallas)
     return EOOperators(
         dhat=lambda v: wops.schur_op(upe, upo, v, mass, twist=twist, **kw),
         dhat_dag=lambda v: wops.schur_op(upe, upo, v, mass, twist=twist,
@@ -189,15 +191,17 @@ def eo_context(u: Array, mass, *, r: float = 1.0, twist: float = 0.0,
         ops = eo_operators_packed(u, mass, r=r, twist=twist, bz=bz,
                                   interpret=interpret)
 
+        xh = u.shape[4] // 2
+
         @phases.scoped(phases.RHS_LAYOUT)
         def prepare(b: Array) -> tuple[Array, Array]:
             b_e, b_o = (jax.vmap(split_eo)(b) if batched else split_eo(b))
-            return pack_spinor(b_e), pack_spinor(b_o)
+            return pack_half_spinor(b_e), pack_half_spinor(b_o)
 
         @phases.scoped(phases.MERGE)
         def finish(x_e: Array, x_o: Array) -> Array:
-            xe = unpack_spinor(x_e, dtype=out_dtype)
-            xo = unpack_spinor(x_o, dtype=out_dtype)
+            xe = unpack_half_spinor(x_e, xh, dtype=out_dtype)
+            xo = unpack_half_spinor(x_o, xh, dtype=out_dtype)
             return (jax.vmap(merge_eo)(xe, xo) if batched
                     else merge_eo(xe, xo))
 

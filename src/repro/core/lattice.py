@@ -14,6 +14,11 @@ Two layouts are used throughout the package:
   This is the FPGA paper's "stream one site per cycle" layout re-thought
   for a (8,128)-register machine: one vector op touches 128 lattice sites.
 
+* **packed half** — the even-odd half fields of the parity kernels:
+  ``psi[T, S=24, Z, Y*Xh]`` and ``U[mu(4), T, G=18, Z, Y*Xh]`` (see
+  "Packed half fields" below): a parity-compressed row of Xh = X/2 sites
+  is too short for the lanes, so (Y, Xh) fill them together.
+
 The packing functions below are exact bijections; tests round-trip them.
 """
 
@@ -162,8 +167,8 @@ def unpack_gauge(p: jax.Array, dtype=jnp.complex64) -> jax.Array:
 # fields compress the X axis by 2: within the row (t, z, y) the sites of a
 # given parity sit at x = 2*j + s where the row offset s depends only on
 # (t + z + y) mod 2.  Compressed fields keep the natural trailing axes, so
-# an even-parity spinor is (T, Z, Y, X//2, 4, 3) and ``pack_spinor`` /
-# ``unpack_spinor`` apply to half fields unchanged.
+# an even-parity spinor is (T, Z, Y, X//2, 4, 3); ``pack_half_spinor`` /
+# ``pack_half_gauge`` give the parity kernels' packed half layout.
 #
 # The split/merge bijections only require an even X extent (asserted) —
 # the compression never crosses rows.  The even-odd OPERATORS in
@@ -245,6 +250,84 @@ def split_eo_gauge(u: jax.Array) -> tuple[jax.Array, jax.Array]:
 def merge_eo_gauge(u_e: jax.Array, u_o: jax.Array) -> jax.Array:
     """Inverse of :func:`split_eo_gauge`."""
     return jax.vmap(merge_eo)(u_e, u_o)
+
+
+# ---------------------------------------------------------------------------
+# Packed half fields (the parity kernels' layout)
+# ---------------------------------------------------------------------------
+#
+# A parity-compressed row holds only X/2 sites, far fewer than the 128
+# lanes of a vector register, so the half fields put the component axis
+# OUTSIDE the site axes and flatten (Y, Xh) y-major into the lane axis:
+#
+#   psi_half[T, S=24, Z, Y*Xh]       U_half[mu(4), T, G=18, Z, Y*Xh]
+#
+# with S and G packed as in ``pack_spinor``/``pack_gauge``.  Each
+# component is one dense (Z, Y*Xh) slab: Z on sublanes, lane l addressing
+# y = l // Xh, j = l % Xh.  Leading axes (an RHS batch) pass through.  The
+# compressed extent Xh cannot be read back from the flat lane axis, so
+# unpacking (and every parity kernel) takes it explicitly; X is never
+# sharded, so Xh is the same on every shard.
+
+HALF_S_AXIS = -3  # the component axis of a packed half field
+
+
+def _pack_half(f: jax.Array, dtype) -> jax.Array:
+    """(..., T, Z, Y, Xh, a, b) complex -> (..., T, a*b*2, Z, Y*Xh) real."""
+    p = jnp.stack([jnp.real(f).astype(dtype), jnp.imag(f).astype(dtype)],
+                  axis=-1)
+    *lead, t, z, y, xh = f.shape[:-2]
+    p = p.reshape(*lead, t, z, y * xh, -1)
+    return jnp.moveaxis(p, -1, HALF_S_AXIS)
+
+
+def _unpack_half(p: jax.Array, xh: int, inner: tuple[int, int],
+                 dtype) -> jax.Array:
+    """Inverse of :func:`_pack_half` for ``inner`` = (a, b)."""
+    *lead, t, _, z, lanes = p.shape
+    assert lanes % xh == 0, f"lane extent {lanes} is not a multiple of {xh}"
+    q = jnp.moveaxis(p, HALF_S_AXIS, -1)
+    q = q.reshape(*lead, t, z, lanes // xh, xh, *inner, 2)
+    return (q[..., 0] + 1j * q[..., 1]).astype(dtype)
+
+
+def half_rows(p: jax.Array, xh: int) -> jax.Array:
+    """Packed half field (..., T, C, Z, Y*Xh) -> its (..., T, Z, Y, C, Xh)
+    row view (the site axes in front of the components, Xh innermost):
+    what plane-wise code outside the kernels computes on."""
+    *lead, t, c, z, lanes = p.shape
+    q = p.reshape(*lead, t, c, z, lanes // xh, xh)
+    return jnp.moveaxis(q, -4, -2)
+
+
+def half_lanes(q: jax.Array) -> jax.Array:
+    """Inverse of :func:`half_rows`."""
+    *lead, t, z, y, c, xh = q.shape
+    return jnp.moveaxis(q, -2, -4).reshape(*lead, t, c, z, y * xh)
+
+
+def pack_half_spinor(psi: jax.Array, dtype=jnp.float32) -> jax.Array:
+    """(..., T, Z, Y, Xh, 4, 3) complex half field -> (..., T, 24, Z, Y*Xh)."""
+    return _pack_half(psi, dtype)
+
+
+def unpack_half_spinor(p: jax.Array, xh: int,
+                       dtype=jnp.complex64) -> jax.Array:
+    """(..., T, 24, Z, Y*Xh) real -> (..., T, Z, Y, Xh, 4, 3) complex."""
+    assert p.shape[HALF_S_AXIS] == SPINOR_S
+    return _unpack_half(p, xh, (NSPIN, NCOL), dtype)
+
+
+def pack_half_gauge(u: jax.Array, dtype=jnp.float32) -> jax.Array:
+    """(4, T, Z, Y, Xh, 3, 3) per-parity links -> (4, T, 18, Z, Y*Xh)."""
+    return _pack_half(u, dtype)
+
+
+def unpack_half_gauge(p: jax.Array, xh: int,
+                      dtype=jnp.complex64) -> jax.Array:
+    """(4, T, 18, Z, Y*Xh) real -> (4, T, Z, Y, Xh, 3, 3) complex."""
+    assert p.shape[HALF_S_AXIS] == GAUGE_G
+    return _unpack_half(p, xh, (NCOL, NCOL), dtype)
 
 
 # ---------------------------------------------------------------------------

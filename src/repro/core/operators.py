@@ -48,7 +48,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from repro.core.lattice import NCOL, NSPIN
+from repro.core.lattice import HALF_S_AXIS, NCOL, NSPIN
 from repro.core.wilson import (apply_gamma5, dslash, dslash_eo, dslash_oe,
                                schur_dagger, schur_op)
 
@@ -77,21 +77,22 @@ def unknown_name(kind: str, value, allowed) -> str:
 # ---------------------------------------------------------------------------
 
 
-def apply_igamma5_packed(p: Array) -> Array:
-    """(i gamma5) on a packed field's S axis (-2); leading axes pass through.
+def apply_igamma5_packed(p: Array, axis: int = -2) -> Array:
+    """(i gamma5) on a packed field's S ``axis``; the other axes pass
+    through (-2: the full-lattice packed layout, ``HALF_S_AXIS``: a packed
+    half field).
 
     In the packed real layout the S axis interleaves (spin, color, re/im),
     so multiplying by i swaps the re/im planes (re' = -im, im' = re) and
     gamma5 = diag(+,+,-,-) signs the spin blocks.
     """
-    s, x = p.shape[-2:]
-    assert s == NSPIN * NCOL * 2
-    q = p.reshape(p.shape[:-2] + (NSPIN, NCOL, 2, x))
-    re, im = q[..., 0, :], q[..., 1, :]  # each (..., NSPIN, NCOL, X)
-    sign = jnp.asarray([1.0, 1.0, -1.0, -1.0],
-                       p.dtype).reshape((NSPIN, 1, 1))
-    out = jnp.stack([-sign * im, sign * re], axis=-2)
-    return out.reshape(p.shape)
+    q = jnp.moveaxis(p, axis, -1)
+    assert q.shape[-1] == NSPIN * NCOL * 2
+    q = q.reshape(q.shape[:-1] + (NSPIN, NCOL, 2))
+    re, im = q[..., 0], q[..., 1]  # each (..., NSPIN, NCOL)
+    sign = jnp.asarray([1.0, 1.0, -1.0, -1.0], p.dtype).reshape((NSPIN, 1))
+    out = jnp.stack([-sign * im, sign * re], axis=-1)
+    return jnp.moveaxis(out.reshape(out.shape[:-3] + (-1,)), -1, axis)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -102,9 +103,9 @@ class SiteTerm:
     ``twist == 0.0`` to keep the Wilson path bitwise identical to the
     pre-registry code (``scale`` may be a float or a traced scalar).
     ``apply``/``solve`` dispatch on the field layout — complex arrays are
-    natural layout (gamma5 on spin axis -2), real arrays are the packed
-    (..., 24, X) layout — so the same SiteTerm serves the reference
-    operators, the packed fast path and the halo boundary planes.
+    natural layout (gamma5 on spin axis -2), real arrays are packed half
+    fields (..., T, 24, Z, Y*Xh) — so the same SiteTerm serves the
+    reference operators and the packed even-odd fast path.
     """
 
     scale: object
@@ -132,7 +133,8 @@ class SiteTerm:
             return self.scale * v
         if jnp.iscomplexobj(v):
             return self.scale * v + (1j * self.twist) * apply_gamma5(v)
-        return self.scale * v + self.twist * apply_igamma5_packed(v)
+        return self.scale * v + self.twist * apply_igamma5_packed(
+            v, HALF_S_AXIS)
 
     def solve(self, v: Array) -> Array:
         """S^-1 v (``v / scale`` bitwise when twist == 0 — the historical
@@ -144,7 +146,8 @@ class SiteTerm:
         if jnp.iscomplexobj(v):
             return (self.scale * v
                     - (1j * self.twist) * apply_gamma5(v)) / den
-        return (self.scale * v - self.twist * apply_igamma5_packed(v)) / den
+        return (self.scale * v
+                - self.twist * apply_igamma5_packed(v, HALF_S_AXIS)) / den
 
 
 # ---------------------------------------------------------------------------

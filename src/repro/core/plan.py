@@ -62,9 +62,11 @@ from repro.core.eo import (EOContext, back_substitute_odd, eo_context,
                            schur_rhs)
 from repro.core.lattice import (complex_to_real_pair, field_dot,
                                 field_norm2, field_norm2_batched, merge_eo,
-                                pack_gauge, pack_spinor,
+                                pack_gauge, pack_half_gauge,
+                                pack_half_spinor, pack_spinor,
                                 real_pair_to_complex, split_eo,
-                                split_eo_gauge, unpack_spinor)
+                                split_eo_gauge, unpack_half_spinor,
+                                unpack_spinor)
 from repro.core.operators import (SiteTerm, dslash_g, get_operator,
                                   schur_normal_op_g, unknown_name)
 from repro.core.precision import parse_dtype
@@ -583,7 +585,8 @@ def _solve_eo_mp(plan, u, b, mass, *, tol, maxiter, inner_tol,
         with phases.scope(phases.GAUGE_LAYOUT):
             u_e_lo = ops.u_e.astype(low_dtype)
             u_o_lo = ops.u_o.astype(low_dtype)
-        kkw = dict(twist=twist, bz=plan.bz, interpret=plan.interpret)
+        kkw = dict(xh=u.shape[4] // 2, twist=twist, bz=plan.bz,
+                   interpret=plan.interpret)
 
         def a_low(w):  # low storage in/out, f32 registers inside
             return wops.schur_normal_op(u_e_lo, u_o_lo, w, mass, **kkw)
@@ -777,19 +780,28 @@ def _solve_eo_sharded(plan, u, b, mass, *, tol, maxiter,
     regardless of N.
     """
     batched = plan.batched
+    xh = u.shape[4] // 2
     upe, upo, pb_e, pb_o = _eo_sharded_prep(plan, u, b)
     solver = _sharded_eo_solver(plan, float(mass), float(tol), int(maxiter),
-                                int(residual_replacement_every))
+                                int(residual_replacement_every), xh)
     x_e, x_o, stats = solver(upe, upo, pb_e, pb_o)
     return _merged(x_e, x_o, b.dtype, batched), stats
 
 
 @phases.scoped(phases.MERGE)
 def _merged(x_e: Array, x_o: Array, dtype, batched: bool) -> Array:
-    """The natural-layout solution from packed parity half fields."""
-    xe = unpack_spinor(x_e, dtype=dtype)
-    xo = unpack_spinor(x_o, dtype=dtype)
+    """The natural-layout solution from its natural-layout parity halves
+    (the sharded solves unpack them shard by shard: a reshape of the
+    sharded lane axis has no global-view sharding rule)."""
+    xe, xo = x_e.astype(dtype), x_o.astype(dtype)
     return jax.vmap(merge_eo)(xe, xo) if batched else merge_eo(xe, xo)
+
+
+def _natural_half_spec(plan: SolverPlan):
+    """The shard_map spec of a natural-layout half field (T, Z, Y, Xh, 4,
+    3), batched or not."""
+    spec = dist.lattice_specs(plan.mesh, plan.axis_map)[0]
+    return P(None, *spec) if plan.batched else spec
 
 
 def _eo_sharded_prep(plan: SolverPlan, u: Array, b: Array):
@@ -810,7 +822,8 @@ def _eo_sharded_prep(plan: SolverPlan, u: Array, b: Array):
             "the sharded parity stack hard-codes r=1 (bulk blocks AND "
             f"boundary corrections); got r={plan.r}. Use the single-device "
             "natural-layout path for r != 1.")
-    psi_spec, gauge_spec, sharded = dist.lattice_specs(mesh, plan.axis_map)
+    psi_spec, gauge_spec, sharded = dist.half_lattice_specs(mesh,
+                                                            plan.axis_map)
     dims = b.shape[1:4] if batched else b.shape[:3]
     for mu, (ax, n) in sorted(sharded.items()):
         ext = dims[mu]
@@ -824,10 +837,10 @@ def _eo_sharded_prep(plan: SolverPlan, u: Array, b: Array):
     # global prep in natural layout, then shard the packed parity fields
     with phases.scope(phases.GAUGE_LAYOUT):
         u_e, u_o = split_eo_gauge(u)
-        upe, upo = pack_gauge(u_e), pack_gauge(u_o)
+        upe, upo = pack_half_gauge(u_e), pack_half_gauge(u_o)
     with phases.scope(phases.RHS_LAYOUT):
         b_e, b_o = (jax.vmap(split_eo)(b) if batched else split_eo(b))
-        pb_e, pb_o = pack_spinor(b_e), pack_spinor(b_o)
+        pb_e, pb_o = pack_half_spinor(b_e), pack_half_spinor(b_o)
     bspec = P(None, *psi_spec) if batched else psi_spec
     gput = lambda a: jax.device_put(a, NamedSharding(mesh, gauge_spec))
     sput = lambda a: jax.device_put(a, NamedSharding(mesh, bspec))
@@ -842,18 +855,21 @@ _SHARDED_EO_CACHE: dict = {}
 
 
 def _sharded_eo_solver(plan: SolverPlan, mass: float, tol: float,
-                       maxiter: int, residual_replacement_every: int):
-    key = (plan.cache_key(), mass, tol, maxiter, residual_replacement_every)
+                       maxiter: int, residual_replacement_every: int,
+                       xh: int):
+    key = (plan.cache_key(), mass, tol, maxiter, residual_replacement_every,
+           xh)
     cached = _SHARDED_EO_CACHE.get(key)
     if cached is not None:
         return cached
     mesh = plan.mesh
     batched = plan.batched
-    psi_spec, gauge_spec, sharded = dist.lattice_specs(mesh, plan.axis_map)
+    psi_spec, gauge_spec, sharded = dist.half_lattice_specs(mesh,
+                                                            plan.axis_map)
     bspec = P(None, *psi_spec) if batched else psi_spec
     site = _family_site(plan, mass)  # registry site term, contract-checked
     twist = site.twist
-    kkw = dict(sharded=sharded, use_pallas=plan.backend == "pallas",
+    kkw = dict(sharded=sharded, xh=xh, use_pallas=plan.backend == "pallas",
                bz=plan.bz, interpret=plan.interpret)
     skw = dict(twist=twist, **kkw)
     pdot, pnorm2 = dist.make_psum_dots(mesh, batched=batched)
@@ -879,15 +895,18 @@ def _sharded_eo_solver(plan: SolverPlan, mass: float, tol: float,
                                  dot=pdot, norm2=pnorm2, batched=batched)
         with phases.scope(phases.BACK_SUBSTITUTE):
             x_o = m_inv(pbo_l - d_oe(x_e))
-        return x_e, x_o, st
+        with phases.scope(phases.MERGE):
+            return (unpack_half_spinor(x_e, xh), unpack_half_spinor(x_o, xh),
+                    st)
 
     stats_spec = solvers.SolveStats(P(), P(), P(), P(),
                                     P() if batched else None,
                                     verdict=P(), matvecs=P())
+    nspec = _natural_half_spec(plan)
     solver = jax.jit(jax.shard_map(
         local_solve, mesh=mesh,
         in_specs=(gauge_spec, gauge_spec, bspec, bspec),
-        out_specs=(bspec, bspec, stats_spec),
+        out_specs=(nspec, nspec, stats_spec),
         check_vma=False))
     _SHARDED_EO_CACHE[key] = solver
     return solver
@@ -1036,7 +1055,8 @@ def _loop_program_eo_mp(plan, u, b, mass, *, tol, maxiter, inner_tol,
         with phases.scope(phases.GAUGE_LAYOUT):
             u_e_lo = ops.u_e.astype(low_dtype)
             u_o_lo = ops.u_o.astype(low_dtype)
-        kkw = dict(twist=twist, bz=plan.bz, interpret=plan.interpret)
+        kkw = dict(xh=u.shape[4] // 2, twist=twist, bz=plan.bz,
+                   interpret=plan.interpret)
 
         def a_low(w):
             return wops.schur_normal_op(u_e_lo, u_o_lo, w, mass, **kkw)
@@ -1145,7 +1165,8 @@ _SHARDED_EO_SEG_CACHE: dict = {}
 
 
 def _sharded_eo_segment_fns(plan: SolverPlan, mass: float, tol: float,
-                            maxiter: int, residual_replacement_every: int):
+                            maxiter: int, residual_replacement_every: int,
+                            xh: int):
     """The sharded even-odd solve split into start/step/finish shard_maps.
 
     Each function rebuilds the SAME LoopParts inside its trace (the
@@ -1157,17 +1178,19 @@ def _sharded_eo_segment_fns(plan: SolverPlan, mass: float, tol: float,
     (fields sharded, scalars/masks replicated) and stays resident on the
     mesh between segments.
     """
-    key = (plan.cache_key(), mass, tol, maxiter, residual_replacement_every)
+    key = (plan.cache_key(), mass, tol, maxiter, residual_replacement_every,
+           xh)
     cached = _SHARDED_EO_SEG_CACHE.get(key)
     if cached is not None:
         return cached
     mesh = plan.mesh
     batched = plan.batched
-    psi_spec, gauge_spec, sharded = dist.lattice_specs(mesh, plan.axis_map)
+    psi_spec, gauge_spec, sharded = dist.half_lattice_specs(mesh,
+                                                            plan.axis_map)
     bspec = P(None, *psi_spec) if batched else psi_spec
     site = _family_site(plan, mass)
     twist = site.twist
-    kkw = dict(sharded=sharded, use_pallas=plan.backend == "pallas",
+    kkw = dict(sharded=sharded, xh=xh, use_pallas=plan.backend == "pallas",
                bz=plan.bz, interpret=plan.interpret)
     skw = dict(twist=twist, **kkw)
     pdot, pnorm2 = dist.make_psum_dots(mesh, batched=batched)
@@ -1222,7 +1245,9 @@ def _sharded_eo_segment_fns(plan: SolverPlan, mass: float, tol: float,
         parts, m_inv, d_oe = make_parts(upe_l, upo_l, pbe_l, pbo_l)
         x_e, stats = parts.finish(carry)
         x_o = m_inv(pbo_l - d_oe(x_e))
-        return x_e, x_o, stats
+        with phases.scope(phases.MERGE):
+            return (unpack_half_spinor(x_e, xh), unpack_half_spinor(x_o, xh),
+                    stats)
 
     start = jax.jit(jax.shard_map(
         local_start, mesh=mesh, in_specs=gspecs,
@@ -1230,9 +1255,10 @@ def _sharded_eo_segment_fns(plan: SolverPlan, mass: float, tol: float,
     step = jax.jit(jax.shard_map(
         local_step, mesh=mesh, in_specs=gspecs + (carry_spec, P()),
         out_specs=(carry_spec, P()), check_vma=False))
+    nspec = _natural_half_spec(plan)
     finish = jax.jit(jax.shard_map(
         local_finish, mesh=mesh, in_specs=gspecs + (carry_spec,),
-        out_specs=(bspec, bspec, stats_spec), check_vma=False))
+        out_specs=(nspec, nspec, stats_spec), check_vma=False))
     fns = (start, step, finish)
     _SHARDED_EO_SEG_CACHE[key] = fns
     return fns
@@ -1248,10 +1274,11 @@ def _loop_program_eo_sharded(plan, u, b, mass, *, tol, maxiter,
     restores on a smaller mesh or on CPU (the elastic-resume contract).
     """
     batched = plan.batched
+    xh = u.shape[4] // 2
     upe, upo, pb_e, pb_o = _eo_sharded_prep(plan, u, b)
     start_f, step_f, finish_f = _sharded_eo_segment_fns(
         plan, float(mass), float(tol), int(maxiter),
-        int(residual_replacement_every))
+        int(residual_replacement_every), xh)
 
     def start():
         return start_f(upe, upo, pb_e, pb_o)

@@ -1,9 +1,10 @@
-"""Pallas TPU kernels for the Dirac-Wilson stencil (packed layout).
+"""Pallas TPU kernels for the Dirac-Wilson stencil (packed layouts).
 
 This is the TPU re-think of the paper's FPGA compute kernel (Fig. 1) and
 cyclic-buffer transport (its Ref. [11]):
 
-* **grid = (T, Z/BZ[, Y/BY][, N])** — the kernel streams (t, z-block)
+* **grid = (T, Z/BZ[, Y/BY][, N])** (full lattice; the parity kernels
+  stream whole t-planes, grid (T[, N])) — the kernel streams (t, z-block)
   lattice *planes* (optionally further tiled along Y, optionally with the
   RHS batch as the trailing grid axis); Pallas's software pipeline
   double-buffers the next planes' HBM->VMEM DMA behind the current
@@ -26,7 +27,8 @@ cyclic-buffer transport (its Ref. [11]):
   constant coefficients), so D†ψ = γ5 D γ5 ψ and the CGNR normal operator
   cost ZERO extra full-field HBM passes versus plain D.
 
-**Launch space (DESIGN.md §13).**  Tile parameters — z-block ``bz``,
+**Launch space (DESIGN.md §13).**  Tile parameters of the full-lattice
+kernel — z-block ``bz``,
 y-block ``by``, RHS-batch placement ``batch`` ("block" keeps the whole
 batch inside every block; "grid" makes it the trailing, fastest-varying
 grid axis so consecutive steps revisit one gauge block), and gauge
@@ -39,7 +41,9 @@ When none is given explicitly the wrappers consult the autotuner's
 checked-in ``kernels/tuning_cache.json`` (:func:`repro.kernels.dispatch.
 pick_tile`).  On a cold or disabled cache a launch takes the largest
 tile that fits the scoped VMEM budget, starting from the historical
-default (bz ≤ 4, full Y), which stands wherever it fits.
+default (bz ≤ 4, full Y), which stands wherever it fits.  The parity
+kernels have one knob, ``bz``, the Z rows of one body chunk, and consult
+no cache; their RHS batch always rides the grid.
 
 **Lowerings.**  ``interpret=None`` interprets on CPU and compiles
 (Mosaic) on GPU/TPU; ``interpret=False`` on CPU routes to the
@@ -48,30 +52,34 @@ compiled-XLA half-spinor implementation in
 on the CPU backend, and the XLA path is this host's honest compiled
 number (see :func:`repro.kernels.dispatch.resolve_lowering`).
 
-Two kernel families share the machinery:
+Two kernel families share the per-site machinery (``_hop``, the tables):
 
-* ``dslash_pallas``      — the full-lattice operator (mass term + 8 hops);
+* ``dslash_pallas``      — the full-lattice operator (mass term + 8 hops)
+  on the (T, Z, Y, 24, X) packed layout, with the launch space above;
 * ``dslash_eo_pallas`` / ``dslash_oe_pallas`` — the even-odd parity hop
-  blocks D_eo / D_oe on half fields whose X axis is parity-compressed by 2
-  (see :mod:`repro.core.lattice`).  Within a row (t, z, y) the x-neighbour
-  of compressed index j is j + s (forward) / j - (1 - s) (backward) where
-  s is the output row's parity offset — realised as a per-row select
-  between the block and its lane-rolled copy.  The parity kernels also
-  take an optional accumulator operand (``psi_acc``/``acc_coeff``/
-  ``hop_coeff``) so the Schur complement m·ψ - D_eo D_oe ψ / m is TWO
-  kernel launches with the axpy folded into the second epilogue — no
-  separate full-field scale/add passes.
+  blocks D_eo / D_oe on packed HALF fields (T, 24, Z, Y*Xh) (see
+  :mod:`repro.core.lattice`): the component axis sits outside the site
+  axes and (Y, Xh) fill the lanes, so every component is a dense
+  (Z, Y*Xh) slab.  A grid step is one t-plane; Z hops are sublane rolls,
+  Y hops lane rolls by Xh, and the parity-compressed X hop is a lane roll
+  by 1 under two selects (the row's output-site offset, and the wrap at
+  the row ends).  The parity kernels also take an optional accumulator
+  operand (``psi_acc``/``acc_coeff``/``hop_coeff``) so the Schur
+  complement m·ψ - D_eo D_oe ψ / m is TWO kernel launches with the axpy
+  folded into the second epilogue — no separate full-field scale/add
+  passes.
 
 Both families are **multi-RHS batched**: a spinor field may carry a leading
-RHS-batch axis (N, T, Z, Y, 24, X).  The batched BlockSpecs pin the batch
-block index to 0 (the whole batch rides in each block) while the gauge
-BlockSpecs are untouched — so one HBM fetch of a gauge plane (8 links ×
-18 reals = 144 reals/site) feeds all N spinor planes (24 in + 24 out
-reals/site each), shrinking per-RHS traffic from 144+48 to 144/N+48
-reals/site: an up-to (144+48)/48 ≈ 4x arithmetic-intensity gain before
-the compute roof (see DESIGN.md §6).  The kernel bodies are
-rank-polymorphic (negative-axis rolls/shifts, broadcasting selects), so
-batching adds ZERO trace-time unrolling — compile time is independent of N.
+RHS-batch axis.  The gauge BlockSpecs never carry it: with the whole batch
+in each spinor block (batch block index pinned to 0), or with the batch on
+the innermost grid axis (always so in the parity kernels, where
+consecutive steps ask for the same gauge blocks), one HBM fetch of a gauge plane
+(8 links × 18 reals = 144 reals/site) feeds all N spinor planes (24 in +
+24 out reals/site each), shrinking per-RHS traffic from 144+48 to
+144/N+48 reals/site: an up-to (144+48)/48 ≈ 4x arithmetic-intensity gain
+before the compute roof (see DESIGN.md §6).  The kernel bodies broadcast
+over the batch, so batching adds ZERO trace-time unrolling — compile time
+is independent of N.
 
 The kernels compute in f32 registers regardless of the (bf16/f32) storage
 dtype — narrow storage, wide accumulate, like the FPGA DSP datapath.
@@ -250,12 +258,6 @@ def _roll_sc(lists, shift, axis):
     return [[jnp.roll(e, shift, axis=axis) for e in row] for row in lists]
 
 
-def _where_sc(sel, a_lists, b_lists):
-    """Elementwise select between two [..][..] lists of (..., Y, X) blocks."""
-    return [[jnp.where(sel, a, b) for a, b in zip(ra, rb)]
-            for ra, rb in zip(a_lists, b_lists)]
-
-
 def _shift(lists, boundary, forward: bool, axis: int):
     """Shift [..][..] lists of (..., BZ, BY, X) along ``axis``, splicing
     the boundary plane (extent 1 on that axis) in at the open end.
@@ -406,12 +408,12 @@ def _gauge_specs(t: int, z: int, bz: int, y: int, by: int, x: int,
     return specs
 
 
-# Scoped VMEM of one launch.  Mosaic's default scoped limit is 16 MiB on
-# v5e.  The Pallas pipeline double-buffers every block, and a VMEM tile
-# pads a block's last two dims to (8·4/itemsize, 128).  The unrolled
-# kernel body keeps about 1,200 f32 (…, X) vectors live per (z, y) row of
-# the block, each padded to 128 lanes (Mosaic's scoped-VMEM reports for
-# the parity kernels at 24³×48); _BODY_ROWS adds a margin to that.
+# Scoped VMEM of one full-lattice launch.  Mosaic's default scoped limit
+# is 16 MiB on v5e.  The Pallas pipeline double-buffers every block, and
+# a VMEM tile pads a block's last two dims to (8·4/itemsize, 128).  The
+# unrolled kernel body keeps about 1,200 f32 (…, X) vectors live per
+# (z, y) row of the block, each padded to 128 lanes (Mosaic's
+# scoped-VMEM reports at 24³×48); _BODY_ROWS adds a margin to that.
 SCOPED_VMEM_BYTES = 16 * 2**20
 VMEM_BUDGET_BYTES = SCOPED_VMEM_BYTES * 7 // 8
 _BODY_ROWS = 1400
@@ -427,31 +429,25 @@ def _padded_bytes(shape: tuple[int, ...], dtype) -> int:
 
 
 def launch_vmem_bytes(bz: int, by: int, y: int, x: int, nbb: int, dtype,
-                      gauge_dtype, gauge_centers: int,
-                      spinor_blocks: int) -> int:
-    """Scoped VMEM bytes of one plane-streaming launch: its double-buffered
+                      gauge_dtype) -> int:
+    """Scoped VMEM bytes of one full-lattice launch: its double-buffered
     blocks plus the kernel body's f32 working set.
 
     ``nbb`` RHS ride in every spinor block ("block" placement; 1 for
-    "grid" or no batch); ``gauge_centers`` is the number of 4-direction
-    gauge operands (1 full lattice, 2 parity); ``spinor_blocks`` counts the
-    (bz, by) spinor blocks: center, t±1 and the output, plus an
-    accumulator where the launch has one.
+    "grid" or no batch).  Spinor blocks: center, t±1 and the output.
     """
     sp = lambda zb, yb: _padded_bytes((nbb, zb, yb, SPINOR_S, x), dtype)
     ga = lambda d, zb, yb: _padded_bytes((d, zb, yb, GAUGE_G, x),
                                          gauge_dtype)
-    total = (spinor_blocks * sp(bz, by) + 2 * sp(1, by)
-             + gauge_centers * ga(NDIRS, bz, by) + ga(1, bz, by)
-             + ga(1, 1, by))
+    total = (4 * sp(bz, by) + 2 * sp(1, by) + ga(NDIRS, bz, by)
+             + ga(1, bz, by) + ga(1, 1, by))
     if by < y:
         total += 2 * sp(bz, 1) + ga(1, bz, 1)
     lane_row = 4 * (-(-x // 128) * 128)   # one f32 (…, X) row in VMEM
     return 2 * total + _BODY_ROWS * nbb * bz * by * lane_row
 
 
-def _fit_tile(z: int, y: int, x: int, nb: int | None, dtype, gauge_dtype,
-              gauge_centers: int, spinor_blocks: int):
+def _fit_tile(z: int, y: int, x: int, nb: int | None, dtype, gauge_dtype):
     """The default tile: the largest block that fits the VMEM budget.
 
     Candidates run from the historical default (bz = largest divisor of
@@ -466,12 +462,10 @@ def _fit_tile(z: int, y: int, x: int, nb: int | None, dtype, gauge_dtype,
         nbb = nb if (nb is not None and batch == "block") else 1
         for by in reversed(_divisors(y)):
             for bz in bzs:
-                if launch_vmem_bytes(bz, by, y, x, nbb, dtype, gauge_dtype,
-                                     gauge_centers, spinor_blocks) \
-                        <= VMEM_BUDGET_BYTES:
+                if launch_vmem_bytes(bz, by, y, x, nbb, dtype,
+                                     gauge_dtype) <= VMEM_BUDGET_BYTES:
                     return bz, by, batch
-    need = launch_vmem_bytes(1, 1, y, x, 1, dtype, gauge_dtype,
-                             gauge_centers, spinor_blocks)
+    need = launch_vmem_bytes(1, 1, y, x, 1, dtype, gauge_dtype)
     raise ValueError(
         f"no dslash tile fits VMEM for a (Z, Y, X) = {(z, y, x)} plane of "
         f"{jnp.dtype(dtype).name} spinors (nrhs={nb or 1}): the smallest "
@@ -480,8 +474,7 @@ def _fit_tile(z: int, y: int, x: int, nb: int | None, dtype, gauge_dtype,
 
 
 def _resolve_tile(bz, by, batch, stream, t, z, y, x, nb, dtype, *,
-                  gauge_dtype, gauge_centers: int = 1,
-                  spinor_blocks: int = 4):
+                  gauge_dtype):
     """Explicit args > tuning cache > defaults.
 
     Any explicitly-passed knob disables the cache for the whole launch
@@ -492,8 +485,7 @@ def _resolve_tile(bz, by, batch, stream, t, z, y, x, nb, dtype, *,
     if bz is None and by is None and batch is None and stream is None:
         tile = pick_tile((t, z, y, x), nb or 1, dtype)
         if tile == DEFAULT_TILE:
-            bz, by, batch = _fit_tile(z, y, x, nb, dtype, gauge_dtype,
-                                      gauge_centers, spinor_blocks)
+            bz, by, batch = _fit_tile(z, y, x, nb, dtype, gauge_dtype)
         else:
             bz, by, batch, stream = tile.bz, tile.by, tile.batch, tile.stream
     batch = batch or "block"
@@ -735,8 +727,7 @@ def dslash_pallas(up: jax.Array, pp: jax.Array, mass: float, *,
                                gamma5_in=gamma5_in, gamma5_out=gamma5_out)
 
     bz, by, batch, stream, y_tiled, grid_batch = _resolve_tile(
-        bz, by, batch, stream, t, z, y, x, nb, pp.dtype,
-        gauge_dtype=up.dtype)
+        bz, by, batch, stream, t, z, y, x, nb, pp.dtype, gauge_dtype=up.dtype)
     stream_db = stream == "db"
 
     psi_specs = _spinor_specs(t, z, bz, y, by, x, nb, grid_batch)
@@ -763,226 +754,340 @@ def dslash_pallas(up: jax.Array, pp: jax.Array, mass: float, *,
 
 
 # ---------------------------------------------------------------------------
-# Parity (even-odd) hop kernels on half fields
+# Parity (even-odd) hop kernels on packed half fields
+#
+# Layout (repro.core.lattice): psi[T, 24, Z, Y*Xh], U[mu, T, 18, Z, Y*Xh].
+# Each grid step is one t-plane; its blocks hold whole (component, Z,
+# Y*Xh) planes, so every hop but T's stays inside the block:
+#
+# * T: the t±1 planes (and U_t(t-1)) through their own BlockSpecs;
+# * Z: a sublane roll of the block's rows, with the row that crosses the
+#   chunk edge read from the plane itself;
+# * Y: a lane roll by ±Xh — y-major lanes make it periodic in Y as is;
+# * X: a lane roll by ±1 with two selects: the row offset s_out (which
+#   sites of the row are the output parity) and the wrap at j = 0 / Xh-1.
+#
+# The body computes on chunks of ``bz`` Z-rows at a time (the sublane
+# block): each operand's chunk is loaded straight from its ref as a
+# (component, bz, Y*Xh) stack, every component a dense slab.
 # ---------------------------------------------------------------------------
+
+# A parity launch's blocks are whole planes, so it asks Mosaic for more
+# scoped VMEM than the 16 MiB default (v5e has 128 MiB).  Beyond its
+# double-buffered blocks the body keeps 150-250 f32 (bz, Y*Xh) slabs a
+# right-hand side (Mosaic's scoped-VMEM needs at 24³×48 and at 16³×32
+# with N = 8); _PARITY_BODY_SLABS adds a margin to that.
+PARITY_VMEM_LIMIT_BYTES = 96 * 2**20
+PARITY_VMEM_BUDGET_BYTES = PARITY_VMEM_LIMIT_BYTES * 7 // 8
+_PARITY_BODY_SLABS = 300
+
+
+def parity_vmem_bytes(bz: int, z: int, lanes: int, dtype, gauge_dtype,
+                      has_acc: bool) -> int:
+    """Scoped VMEM bytes of one parity launch with one right-hand side a
+    grid step: its double-buffered plane blocks plus the body's working
+    set on a ``bz``-row chunk.
+
+    Spinor blocks: center, t±1 and the output, plus the accumulator;
+    gauge blocks: the output-parity links (4 directions) and the
+    neighbour parity's z/y/x links at t and t-links at t-1.
+    """
+    sp = _padded_bytes((SPINOR_S, z, lanes), dtype)
+    ga = _padded_bytes((GAUGE_G, z, lanes), gauge_dtype)
+    blocks = (4 + bool(has_acc)) * sp + (NDIRS + 4) * ga
+    body = _PARITY_BODY_SLABS * _padded_bytes((bz, lanes), jnp.float32)
+    return 2 * blocks + body
+
+
+def _parity_chunks(z: int, dtype, gauge_dtype) -> list[int]:
+    """The legal chunks of a Z extent, the default's order first.
+
+    Mosaic loads a run of rows at a traced offset only when the offset is
+    a multiple of the (8, 128) tile, and the single edge row a chunk's Z
+    hop reads only from 32-bit operands.  So 32-bit fields take whole
+    sublane tiles (smallest first), the whole Z or one row; narrower
+    storage (bf16) takes the whole Z in one chunk, which has no traced
+    offset at all.
+    """
+    if min(jnp.dtype(dtype).itemsize, jnp.dtype(gauge_dtype).itemsize) < 4:
+        return [z]
+    tiles = [c for c in _divisors(z) if c % 8 == 0]
+    return list(dict.fromkeys(tiles + [z, 1]))
+
+
+def _resolve_parity_tile(bz, dims, dtype, *, gauge_dtype,
+                         has_acc: bool) -> int:
+    """The Z rows of one body chunk: ``bz`` if given, else the first legal
+    chunk (:func:`_parity_chunks`) that fits VMEM with one right-hand side
+    a grid step — one sublane tile (bz = 8 for f32) at 24³×48, 16³×32 and
+    32³×64."""
+    t, z, y, xh = dims
+    lanes = y * xh
+    chunks = _parity_chunks(z, dtype, gauge_dtype)
+    if bz is not None:
+        bz = _pick_bz(z, bz)
+        if bz not in chunks:
+            raise ValueError(
+                f"bz={bz} is not a parity chunk of the Z extent {z} for "
+                f"{jnp.dtype(dtype).name} spinors and "
+                f"{jnp.dtype(gauge_dtype).name} links: f32 chunks are one "
+                f"row, the whole Z or whole (8, 128) tiles, narrower ones "
+                f"the whole Z; legal bz values: {sorted(chunks)}")
+        return bz
+    for bz in chunks:
+        if parity_vmem_bytes(bz, z, lanes, dtype, gauge_dtype,
+                             has_acc) <= PARITY_VMEM_BUDGET_BYTES:
+            return bz
+    small = min(chunks)
+    need = parity_vmem_bytes(small, z, lanes, dtype, gauge_dtype, has_acc)
+    raise ValueError(
+        f"no parity tile fits VMEM for a (Z, Y*Xh) = {(z, lanes)} plane of "
+        f"{jnp.dtype(dtype).name} spinors: the smallest chunk bz={small} "
+        f"needs {need} bytes against a budget of {PARITY_VMEM_BUDGET_BYTES}")
+
+
+def _roll(v, shift: int, axis: int):
+    """``jnp.roll(v, shift, axis)`` through the TPU's native rotate."""
+    axis %= v.ndim
+    shift %= v.shape[axis]
+    return v if shift == 0 else pltpu.roll(v, shift, axis)
+
+
+def _sc(v):
+    """[spin][color] re/im lists of a (…, 24, bz, L) component stack."""
+    c = lambda k: v[..., k, :, :]
+    re = [[c((s * NCOL + col) * 2) for col in range(NCOL)]
+          for s in range(NSPIN)]
+    im = [[c((s * NCOL + col) * 2 + 1) for col in range(NCOL)]
+          for s in range(NSPIN)]
+    return re, im
+
+
+def _links(v):
+    """[row][col] re/im lists of an (18, bz, L) link-component stack."""
+    c = lambda k: v[..., k, :, :]
+    re = [[c((a * NCOL + b) * 2) for b in range(NCOL)] for a in range(NCOL)]
+    im = [[c((a * NCOL + b) * 2 + 1) for b in range(NCOL)]
+          for a in range(NCOL)]
+    return re, im
+
+
+def _z_neighbour(v, load, z0, bz: int, z: int, forward: bool):
+    """The chunk ``v`` (rows z0..z0+bz-1 of a plane), each row replaced by
+    its Z neighbour (z+1 forward, z-1 backward, periodic).
+    ``load(start, n)`` reads n rows of the plane."""
+    if bz == z:
+        return _roll(v, -1 if forward else 1, -2)
+    edge = load((z0 + bz) % z if forward else (z0 - 1 + z) % z, 1)
+    if bz == 1:  # the whole chunk is the row that crosses the edge
+        return edge
+    body = _roll(v, -1 if forward else 1, -2)
+    row = jax.lax.broadcasted_iota(jnp.int32, (bz, 1), 0)
+    return jnp.where(row == (bz - 1 if forward else 0), edge, body)
 
 
 def _dslash_parity_kernel(*refs, parity: int, hop_coeff: float,
                           acc_coeff: float, has_acc: bool,
                           hop_twist: float = 0.0, acc_twist: float = 0.0,
                           g5in: bool, g5out: bool, batched: bool = False,
-                          y_tiled: bool = False, stream_db: bool = False,
-                          bz_sz: int = 0):
+                          xh: int, bz: int):
     """Half-lattice hopping block: hop_coeff · γ5out Hop(γ5in ψ) [+ acc].
 
     ``u_oc`` holds the links attached to the OUTPUT-parity sites (forward
-    hops use U_mu(x) at the output site x), ``u_nc``/``u_ntm``/``u_nzm``
-    (and ``u_nym`` when Y is tiled) the links attached to the neighbour
-    parity (backward hops use U_mu(x-mu)† at the neighbour site).
-    ``parity`` selects which parity the output sites are: output rows sit
-    at x = 2j + s_out with s_out = (t + z + y + parity) mod 2.
+    hops use U_mu(x) at the output site x); ``u_nz``/``u_ny``/``u_nx``/
+    ``u_ntm`` the links attached to the neighbour parity (backward hops
+    use U_mu(x-mu)† at the neighbour site), z/y/x at t and t at t-1.
+    ``parity`` selects which parity the output sites are: output sites of
+    row (t, z, y) sit at x = 2j + s_out, s_out = (t + z + y + parity) mod 2.
 
-    ``batched``: the spinor blocks (center, neighbours, accumulator, out)
-    carry a leading RHS-batch axis; the gauge blocks never do — one gauge
-    fetch feeds all N half-spinor planes, and every hop below is rank-
-    polymorphic (negative-axis rolls/shifts, broadcasting selects).
+    ``batched``: the spinor blocks carry a leading (size-1) RHS-batch
+    axis; the gauge blocks never do — a batch rides the grid, and one
+    gauge fetch feeds all N half-spinor planes of a t.
     """
-    psi_ym = psi_yp = u_nym = None
-    if stream_db:
-        (psi_c, psi_tm, psi_tp, psi_zm, psi_zp, uo_any, un_any,
-         u_ntm, u_nzm, *rest) = refs
-        out_ref = rest[-5]
-        uo_vmem, un_vmem, sem_o, sem_n = rest[-4:]
-        rest = rest[:-4]
-    elif y_tiled:
-        (psi_c, psi_tm, psi_tp, psi_zm, psi_zp, psi_ym, psi_yp,
-         u_oc, u_nc, u_ntm, u_nzm, u_nym, *rest) = refs
-        out_ref = rest[-1]
-    else:
-        (psi_c, psi_tm, psi_tp, psi_zm, psi_zp,
-         u_oc, u_nc, u_ntm, u_nzm, *rest) = refs
-        out_ref = rest[-1]
+    (psi_c, psi_tm, psi_tp, u_oc, u_nz, u_ny, u_nx, u_ntm, *rest) = refs
+    out_ref = rest[-1]
     acc_ref = rest[0] if has_acc else None
+    f32 = jnp.float32
+    z, lanes = psi_c.shape[-2:]
+    lead = (slice(None), 0) if batched else (0,)
 
-    pc_r, pc_i = _split_spinor_block(_take_plane(psi_c, batched))
-    ptm_r, ptm_i = _split_spinor_block(_take_plane(psi_tm, batched))
-    ptp_r, ptp_i = _split_spinor_block(_take_plane(psi_tp, batched))
-    pzm_r, pzm_i = _split_spinor_block(_take_plane(psi_zm, batched))
-    pzp_r, pzp_i = _split_spinor_block(_take_plane(psi_zp, batched))
-    if stream_db:
-        uov = _db_gauge_plane(uo_any, uo_vmem, sem_o, bz_sz)
-        unv = _db_gauge_plane(un_any, un_vmem, sem_n, bz_sz)
-        uo = [_split_gauge_block(uov[mu]) for mu in range(NDIRS)]
-        un = [_split_gauge_block(unv[mu]) for mu in range(NDIRS)]
-    else:
-        uo = [_split_gauge_block(u_oc[mu, 0]) for mu in range(NDIRS)]
-        un = [_split_gauge_block(u_nc[mu, 0]) for mu in range(NDIRS)]
-    untm_r, untm_i = _split_gauge_block(u_ntm[0, 0])
-    unzm_r, unzm_i = _split_gauge_block(u_nzm[0, 0])
+    def rows(ref, idx):
+        return lambda start, n: ref[idx + (pl.ds(start, n), slice(None))
+                                    ].astype(f32)
 
-    nbz, ny = pc_r[0][0].shape[-3:-1]
-    # Row parity selector: True where the output site offset s_out == 1, i.e.
-    # output sites sit at x = 2j + 1 within the row (see lattice.eo_row_offset).
-    # Shape (BZ, BY, 1) broadcasts across both the lane axis and any leading
-    # RHS-batch axis.  Global row index = t + (zi·bz + local z) +
-    # (yi·by + local y) + parity; the yi·by term appears only when Y is
-    # tiled (otherwise yi == 0 and local y IS global y).
-    zy = (jax.lax.broadcasted_iota(jnp.int32, (nbz, ny, 1), 0)
-          + jax.lax.broadcasted_iota(jnp.int32, (nbz, ny, 1), 1))
-    row = pl.program_id(0) + pl.program_id(1) * nbz + zy + parity
-    if y_tiled:
-        row = row + pl.program_id(2) * ny
-    sel = row % 2 == 1
+    # an operand's rows start..start+n-1, all components: (…, C, n, L)
+    spins = lambda ref: rows(ref, lead + (slice(None),))
+    links = lambda ref, d: rows(ref, (d, 0, slice(None)))
 
-    zero = jnp.zeros(pc_r[0][0].shape, jnp.float32)
-    out_r = [[zero for _ in range(NCOL)] for _ in range(NSPIN)]
-    out_i = [[zero for _ in range(NCOL)] for _ in range(NSPIN)]
+    # lane geometry of the (Y*Xh) axis, shape (1, lanes): y parity and the
+    # row ends j = 0 / j = Xh - 1 of the compressed x index
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
+    yy = lane // xh
+    jj = lane - yy * xh
+    y_odd, j_first, j_last = yy & 1, jj == 0, jj == xh - 1
+    t = pl.program_id(0)
 
-    hop = functools.partial(_hop, g5in=g5in, g5out=g5out)
+    def x_fwd(v, sel):  # value at compressed j + s_out in the row
+        return jnp.where(sel, jnp.where(j_last, _roll(v, xh - 1, -1),
+                                        _roll(v, -1, -1)), v)
 
-    # ---- T direction (mu=0): neighbour planes come from extra refs ----
-    hop(out_r, out_i, ptp_r, ptp_i, uo[0][0], uo[0][1], 0, "fwd")
-    hop(out_r, out_i, ptm_r, ptm_i, untm_r, untm_i, 0, "bwd")
+    def x_bwd(v, sel):  # value at compressed j - (1 - s_out) in the row
+        return jnp.where(sel, v, jnp.where(j_first, _roll(v, 1 - xh, -1),
+                                           _roll(v, 1, -1)))
 
-    # ---- Z direction (mu=1): in-block shift + boundary planes ----
-    fz_r = _shift(pc_r, pzp_r, forward=True, axis=_Z_AXIS)
-    fz_i = _shift(pc_i, pzp_i, forward=True, axis=_Z_AXIS)
-    hop(out_r, out_i, fz_r, fz_i, uo[1][0], uo[1][1], 1, "fwd")
-    bz_r = _shift(pc_r, pzm_r, forward=False, axis=_Z_AXIS)
-    bz_i = _shift(pc_i, pzm_i, forward=False, axis=_Z_AXIS)
-    ubz_r = _shift(un[1][0], unzm_r, forward=False, axis=_Z_AXIS)
-    ubz_i = _shift(un[1][1], unzm_i, forward=False, axis=_Z_AXIS)
-    hop(out_r, out_i, bz_r, bz_i, ubz_r, ubz_i, 1, "bwd")
+    def chunk(z0):
+        # Row parity selector: True where the output site offset s_out == 1
+        # (output sites at x = 2j + 1 within the row; lattice.eo_row_offset)
+        row = jax.lax.broadcasted_iota(jnp.int32, (bz, 1), 0)
+        sel = ((row + y_odd + (t + z0 + parity)) & 1) == 1
+        here = lambda ld: ld(z0, bz)
+        zero = jnp.zeros(psi_c.shape[:-4] + (bz, lanes) if batched
+                         else (bz, lanes), f32)
+        out_r = [[zero for _ in range(NCOL)] for _ in range(NSPIN)]
+        out_i = [[zero for _ in range(NCOL)] for _ in range(NSPIN)]
+        hop = functools.partial(_hop, out_r, out_i, g5in=g5in, g5out=g5out)
+        pc = here(spins(psi_c))
+        zn = lambda v, ld, fwd: _z_neighbour(v, ld, z0, bz, z, fwd)
 
-    # ---- Y direction (mu=2): rolls when the block spans full Y, the
-    # Z-style boundary splice when Y is tiled (bitwise identical) ----
-    if y_tiled:
-        pym_r, pym_i = _split_spinor_block(_take_plane(psi_ym, batched))
-        pyp_r, pyp_i = _split_spinor_block(_take_plane(psi_yp, batched))
-        unym_r, unym_i = _split_gauge_block(u_nym[0, 0])
-        fy_r = _shift(pc_r, pyp_r, forward=True, axis=_Y_AXIS)
-        fy_i = _shift(pc_i, pyp_i, forward=True, axis=_Y_AXIS)
-        hop(out_r, out_i, fy_r, fy_i, uo[2][0], uo[2][1], 2, "fwd")
-        by_r = _shift(pc_r, pym_r, forward=False, axis=_Y_AXIS)
-        by_i = _shift(pc_i, pym_i, forward=False, axis=_Y_AXIS)
-        uby_r = _shift(un[2][0], unym_r, forward=False, axis=_Y_AXIS)
-        uby_i = _shift(un[2][1], unym_i, forward=False, axis=_Y_AXIS)
-        hop(out_r, out_i, by_r, by_i, uby_r, uby_i, 2, "bwd")
-    else:
-        hop(out_r, out_i, _roll_sc(pc_r, -1, _Y_AXIS),
-            _roll_sc(pc_i, -1, _Y_AXIS), uo[2][0], uo[2][1], 2, "fwd")
-        hop(out_r, out_i, _roll_sc(pc_r, 1, _Y_AXIS),
-            _roll_sc(pc_i, 1, _Y_AXIS),
-            _roll_sc(un[2][0], 1, _Y_AXIS), _roll_sc(un[2][1], 1, _Y_AXIS),
-            2, "bwd")
+        # ---- T direction (mu=0): neighbour planes come from extra refs ----
+        hop(*_sc(here(spins(psi_tp))), *_links(here(links(u_oc, 0))),
+            0, "fwd")
+        hop(*_sc(here(spins(psi_tm))), *_links(here(links(u_ntm, 0))),
+            0, "bwd")
+        # ---- Z direction (mu=1): row shifts within the plane ----
+        hop(*_sc(zn(pc, spins(psi_c), True)),
+            *_links(here(links(u_oc, 1))), 1, "fwd")
+        u_nz_rows = links(u_nz, 0)
+        hop(*_sc(zn(pc, spins(psi_c), False)),
+            *_links(zn(here(u_nz_rows), u_nz_rows, False)), 1, "bwd")
+        # ---- Y direction (mu=2): lane rolls by Xh ----
+        hop(*_sc(_roll(pc, -xh, -1)), *_links(here(links(u_oc, 2))),
+            2, "fwd")
+        hop(*_sc(_roll(pc, xh, -1)),
+            *_links(_roll(here(links(u_ny, 0)), xh, -1)), 2, "bwd")
+        # ---- X direction (mu=3): parity-compressed lanes ----
+        hop(*_sc(x_fwd(pc, sel)), *_links(here(links(u_oc, 3))), 3, "fwd")
+        hop(*_sc(x_bwd(pc, sel)),
+            *_links(x_bwd(here(links(u_nx, 0)), sel)), 3, "bwd")
 
-    # ---- X direction (mu=3): parity-compressed lane axis.  The neighbour
-    # of compressed index j is j + s_out (forward) / j - (1 - s_out)
-    # (backward): a per-row select between the block and its rolled copy.
-    hop(out_r, out_i,
-        _where_sc(sel, _roll_sc(pc_r, -1, _X_AXIS), pc_r),
-        _where_sc(sel, _roll_sc(pc_i, -1, _X_AXIS), pc_i),
-        uo[3][0], uo[3][1], 3, "fwd")
-    hop(out_r, out_i,
-        _where_sc(sel, pc_r, _roll_sc(pc_r, 1, _X_AXIS)),
-        _where_sc(sel, pc_i, _roll_sc(pc_i, 1, _X_AXIS)),
-        _where_sc(sel, un[3][0], _roll_sc(un[3][0], 1, _X_AXIS)),
-        _where_sc(sel, un[3][1], _roll_sc(un[3][1], 1, _X_AXIS)), 3, "bwd")
-
-    # ---- epilogue: site-term maps on the hop and the accumulator ----
-    #   out = (acc_coeff + acc_twist·iγ5)(ψ_acc)
-    #       + (hop_coeff + hop_twist·iγ5)(γ5out Hop(γ5in ψ))
-    # A zero-twist epilogue (Wilson) takes the historical branch verbatim
-    # (the bitwise-identity contract of the operator registry).  A twisted
-    # scalar mixes each component's re/im planes with a per-spin-block
-    # sign — still pure trace-time constants, zero extra memory traffic.
-    h = jnp.float32(hop_coeff)
-    if hop_twist == 0.0 and acc_twist == 0.0:
+        # ---- epilogue: site-term maps on the hop and the accumulator ----
+        #   out = (acc_coeff + acc_twist·iγ5)(ψ_acc)
+        #       + (hop_coeff + hop_twist·iγ5)(γ5out Hop(γ5in ψ))
+        # A zero-twist epilogue (Wilson) takes the plain branch (the
+        # bitwise-identity contract of the operator registry).  A twisted
+        # scalar mixes each component's re/im planes with a per-spin-block
+        # sign — trace-time constants, zero extra memory traffic.
+        h = f32(hop_coeff)
         if has_acc:
-            a = jnp.float32(acc_coeff)
-            ac_r, ac_i = _split_spinor_block(_take_plane(acc_ref, batched))
-            out_r = [[h * out_r[s][c] + a * ac_r[s][c] for c in range(NCOL)]
-                     for s in range(NSPIN)]
-            out_i = [[h * out_i[s][c] + a * ac_i[s][c] for c in range(NCOL)]
-                     for s in range(NSPIN)]
-        elif hop_coeff != 1.0:
-            out_r = [[h * e for e in row] for row in out_r]
-            out_i = [[h * e for e in row] for row in out_i]
-    else:
-        if has_acc:
-            a = jnp.float32(acc_coeff)
-            ac_r, ac_i = _split_spinor_block(_take_plane(acc_ref, batched))
-        new_r = [[None] * NCOL for _ in range(NSPIN)]
-        new_i = [[None] * NCOL for _ in range(NSPIN)]
-        for sp in range(NSPIN):
-            g = 1.0 if sp < 2 else -1.0  # γ5 sign of this spin block
+            a = f32(acc_coeff)
+            ac_r, ac_i = _sc(here(spins(acc_ref)))
+        if hop_twist == 0.0 and acc_twist == 0.0:
+            if has_acc:
+                out_r = [[h * out_r[s][c] + a * ac_r[s][c]
+                          for c in range(NCOL)] for s in range(NSPIN)]
+                out_i = [[h * out_i[s][c] + a * ac_i[s][c]
+                          for c in range(NCOL)] for s in range(NSPIN)]
+            elif hop_coeff != 1.0:
+                out_r = [[h * e for e in row_] for row_ in out_r]
+                out_i = [[h * e for e in row_] for row_ in out_i]
+        else:
+            new_r = [[None] * NCOL for _ in range(NSPIN)]
+            new_i = [[None] * NCOL for _ in range(NSPIN)]
+            for sp in range(NSPIN):
+                g = 1.0 if sp < 2 else -1.0  # γ5 sign of this spin block
+                for c in range(NCOL):
+                    nr, ni = h * out_r[sp][c], h * out_i[sp][c]
+                    if hop_twist != 0.0:
+                        hg = f32(hop_twist * g)
+                        nr = nr - hg * out_i[sp][c]
+                        ni = ni + hg * out_r[sp][c]
+                    if has_acc:
+                        nr = nr + a * ac_r[sp][c]
+                        ni = ni + a * ac_i[sp][c]
+                        if acc_twist != 0.0:
+                            ag = f32(acc_twist * g)
+                            nr = nr - ag * ac_i[sp][c]
+                            ni = ni + ag * ac_r[sp][c]
+                    new_r[sp][c], new_i[sp][c] = nr, ni
+            out_r, out_i = new_r, new_i
+        for s in range(NSPIN):
             for c in range(NCOL):
-                nr, ni = h * out_r[sp][c], h * out_i[sp][c]
-                if hop_twist != 0.0:
-                    hg = jnp.float32(hop_twist * g)
-                    nr = nr - hg * out_i[sp][c]
-                    ni = ni + hg * out_r[sp][c]
-                if has_acc:
-                    nr = nr + a * ac_r[sp][c]
-                    ni = ni + a * ac_i[sp][c]
-                    if acc_twist != 0.0:
-                        ag = jnp.float32(acc_twist * g)
-                        nr = nr - ag * ac_i[sp][c]
-                        ni = ni + ag * ac_r[sp][c]
-                new_r[sp][c], new_i[sp][c] = nr, ni
-        out_r, out_i = new_r, new_i
-    packed = _repack_spinor_block(out_r, out_i, out_ref.dtype)
-    if batched:
-        out_ref[:, 0] = packed
+                for reim, val in enumerate((out_r[s][c], out_i[s][c])):
+                    k = (s * NCOL + c) * 2 + reim
+                    out_ref[lead + (k, pl.ds(z0, bz), slice(None))] = (
+                        val.astype(out_ref.dtype))
+
+    if bz == z:
+        chunk(0)
     else:
-        out_ref[0] = packed
+        def body(i, carry):
+            chunk(pl.multiple_of(i * bz, bz))
+            return carry
+        jax.lax.fori_loop(0, z // bz, body, 0)
+
+
+def _parity_specs(t: int, z: int, lanes: int, nb: int | None):
+    """(spinor center, t-1, t+1), gauge (U_out, U_nbr z/y/x, U_nbr t at
+    t-1) BlockSpecs: one whole t-plane per grid step.
+
+    With a batch, the batch is the trailing grid axis: a step holds one
+    right-hand side's planes, and consecutive steps ask for the same
+    gauge blocks, which the pipeline does not refetch.
+    """
+    plane = (1, SPINOR_S, z, lanes)
+
+    def spinor(tmap):
+        if nb is None:
+            return pl.BlockSpec(plane, lambda ti: (tmap(ti), 0, 0, 0))
+        return pl.BlockSpec((1,) + plane,
+                            lambda ti, n: (n, tmap(ti), 0, 0, 0))
+
+    def gauge(ndir, d, tmap):
+        return pl.BlockSpec((ndir, 1, GAUGE_G, z, lanes),
+                            lambda ti, *_: (d, tmap(ti), 0, 0, 0))
+
+    here = lambda ti: ti
+    psi = [spinor(here), spinor(lambda ti: (ti - 1 + t) % t),
+           spinor(lambda ti: (ti + 1) % t)]
+    u = [gauge(NDIRS, 0, here), gauge(1, 1, here), gauge(1, 2, here),
+         gauge(1, 3, here), gauge(1, 0, lambda ti: (ti - 1 + t) % t)]
+    return psi, u
 
 
 def _dslash_parity_pallas(u_out: jax.Array, u_nbr: jax.Array, pp: jax.Array,
-                          *, parity: int, bz: int | None,
-                          by: int | None = None, batch: str | None = None,
-                          stream: str | None = None,
+                          *, xh: int, parity: int, bz: int | None,
                           interpret: bool | None, gamma5_in: bool,
                           gamma5_out: bool, psi_acc: jax.Array | None,
                           acc_coeff: float, hop_coeff: float,
                           acc_twist: float = 0.0,
                           hop_twist: float = 0.0) -> jax.Array:
-    nd, t, z, y, g, x = u_out.shape
+    nd, t, g, z, lanes = u_out.shape
     assert nd == NDIRS and g == GAUGE_G
     assert u_nbr.shape == u_out.shape
-    assert pp.ndim in (5, 6), f"spinor rank must be 5 or 6, got {pp.ndim}"
-    nb = pp.shape[0] if pp.ndim == 6 else None
-    tt, zz, yy, s, xx = pp.shape[-5:]
-    assert (tt, zz, yy, xx) == (t, z, y, x) and s == SPINOR_S
+    assert pp.ndim in (4, 5), f"spinor rank must be 4 or 5, got {pp.ndim}"
+    assert pp.shape[-4:] == (t, SPINOR_S, z, lanes), (pp.shape, u_out.shape)
+    assert lanes % xh == 0, f"lane extent {lanes} is not Y * Xh for Xh={xh}"
+    y = lanes // xh
     assert t % 2 == z % 2 == y % 2 == 0, (
         "even-odd kernels need even T/Z/Y extents: an odd periodic extent "
         f"breaks bipartiteness, got {(t, z, y)}")
+    nb = pp.shape[0] if pp.ndim == 5 else None
 
     lowering = resolve_lowering(interpret)
     if lowering == "xla":
         from repro.kernels.wilson_dslash import xla as _xla
         return _xla.dslash_parity_xla(
-            u_out, u_nbr, pp, parity=int(parity) % 2,
+            u_out, u_nbr, pp, xh=xh, parity=int(parity) % 2,
             gamma5_in=gamma5_in, gamma5_out=gamma5_out, psi_acc=psi_acc,
             acc_coeff=acc_coeff, hop_coeff=hop_coeff,
             acc_twist=acc_twist, hop_twist=hop_twist)
 
-    bz, by, batch, stream, y_tiled, grid_batch = _resolve_tile(
-        bz, by, batch, stream, t, z, y, x, nb, pp.dtype,
-        gauge_dtype=u_out.dtype, gauge_centers=2,
-        spinor_blocks=4 + (psi_acc is not None))
-    stream_db = stream == "db"
-
-    psi_specs = _spinor_specs(t, z, bz, y, by, x, nb, grid_batch)
-    gauge_specs = _gauge_specs(t, z, bz, y, by, x, grid_batch)
-    u_c, u_tm, u_zm = gauge_specs[0], gauge_specs[1], gauge_specs[2]
-    if stream_db:
-        u_c = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
-    in_specs = list(psi_specs) + [u_c, u_c, u_tm, u_zm]
-    operands = [*([pp] * len(psi_specs)), u_out, *([u_nbr] * 3)]
-    if y_tiled:
-        in_specs.append(gauge_specs[3])  # U_y(y-1) boundary, neighbour links
-        operands.append(u_nbr)
+    bz = _resolve_parity_tile(
+        bz, (t, z, y, xh), pp.dtype, gauge_dtype=u_out.dtype,
+        has_acc=psi_acc is not None)
+    psi_specs, u_specs = _parity_specs(t, z, lanes, nb)
+    in_specs = psi_specs + u_specs
+    operands = [pp] * 3 + [u_out] + [u_nbr] * 4
     if psi_acc is not None:
         assert psi_acc.shape == pp.shape
         in_specs.append(psi_specs[0])
@@ -993,23 +1098,21 @@ def _dslash_parity_pallas(u_out: jax.Array, u_nbr: jax.Array, pp: jax.Array,
         hop_coeff=float(hop_coeff), acc_coeff=float(acc_coeff),
         hop_twist=float(hop_twist), acc_twist=float(acc_twist),
         has_acc=psi_acc is not None, g5in=bool(gamma5_in),
-        g5out=bool(gamma5_out), batched=nb is not None, y_tiled=y_tiled,
-        stream_db=stream_db, bz_sz=bz)
+        g5out=bool(gamma5_out), batched=nb is not None, xh=int(xh), bz=bz)
     return pl.pallas_call(
         kernel,
-        grid=_launch_grid(t, z, bz, y, by, nb, y_tiled, grid_batch),
+        grid=(t,) if nb is None else (t, nb),
         in_specs=in_specs,
         out_specs=psi_specs[0],
         out_shape=jax.ShapeDtypeStruct(pp.shape, pp.dtype),
-        scratch_shapes=(_db_scratch(bz, y, x, u_out.dtype, streams=2)
-                        if stream_db else ()),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=PARITY_VMEM_LIMIT_BYTES),
         interpret=lowering == "interpret",
     )(*operands)
 
 
 def dslash_eo_pallas(u_e: jax.Array, u_o: jax.Array, pp_o: jax.Array, *,
-                     bz: int | None = None, by: int | None = None,
-                     batch: str | None = None, stream: str | None = None,
+                     xh: int, bz: int | None = None,
                      interpret: bool | None = None,
                      gamma5_in: bool = False, gamma5_out: bool = False,
                      psi_acc: jax.Array | None = None,
@@ -1019,12 +1122,14 @@ def dslash_eo_pallas(u_e: jax.Array, u_o: jax.Array, pp_o: jax.Array, *,
     """D_eo: odd -> even hopping block on packed half fields.
 
     Args:
-      u_e, u_o: (4, T, Z, Y, 18, Xh) packed per-parity link fields
-                (``pack_gauge`` of ``split_eo_gauge``'s halves).
-      pp_o:     (T, Z, Y, 24, Xh) packed ODD-parity spinor half field, or
-        (N, T, Z, Y, 24, Xh) for an N-RHS batch — the batched BlockSpecs
-        fetch each gauge plane once per grid step and stream all N spinor
-        planes through it (multi-RHS gauge amortization).
+      u_e, u_o: (4, T, 18, Z, Y*Xh) packed per-parity link fields
+                (``pack_half_gauge`` of ``split_eo_gauge``'s halves).
+      pp_o:     (T, 24, Z, Y*Xh) packed ODD-parity spinor half field, or
+        (N, T, 24, Z, Y*Xh) for an N-RHS batch — the batch rides the
+        grid inside each t, so each gauge plane is fetched once for all N
+        spinor planes (multi-RHS gauge amortization).
+      xh:       the parity-compressed X extent X/2 (the lane axis holds
+        Y*Xh sites y-major).
       psi_acc/acc_coeff/hop_coeff: optional fused epilogue
         ``out = acc_coeff * psi_acc + hop_coeff * hop`` (psi_acc is an
         EVEN-parity half field, batched iff ``pp_o`` is) — lets the Schur
@@ -1034,14 +1139,15 @@ def dslash_eo_pallas(u_e: jax.Array, u_o: jax.Array, pp_o: jax.Array, *,
         (trace-time constants; zero extra passes), which is exactly what
         a site-diagonal ``i·μ·γ5`` term (twisted mass) needs to fold its
         Schur blocks into the same two launches as Wilson.
-      bz/by/batch/stream: launch-space knobs (DESIGN.md §13); all None
-        consults the tuning cache, every choice is bitwise-neutral.
+      bz: Z rows per body chunk (DESIGN.md §13): one row, the whole Z or
+        whole sublane tiles; None takes the largest default that fits
+        VMEM.  Every legal choice is bitwise-neutral.
       gamma5_in/gamma5_out: fold γ5 around the hop (tables only, free).
     Returns:
       packed even-parity half field(s), shape/dtype of ``pp_o``.
     """
     return _dslash_parity_pallas(
-        u_e, u_o, pp_o, parity=0, bz=bz, by=by, batch=batch, stream=stream,
+        u_e, u_o, pp_o, xh=xh, parity=0, bz=bz,
         interpret=interpret,
         gamma5_in=gamma5_in, gamma5_out=gamma5_out, psi_acc=psi_acc,
         acc_coeff=acc_coeff, hop_coeff=hop_coeff,
@@ -1049,8 +1155,7 @@ def dslash_eo_pallas(u_e: jax.Array, u_o: jax.Array, pp_o: jax.Array, *,
 
 
 def dslash_oe_pallas(u_e: jax.Array, u_o: jax.Array, pp_e: jax.Array, *,
-                     bz: int | None = None, by: int | None = None,
-                     batch: str | None = None, stream: str | None = None,
+                     xh: int, bz: int | None = None,
                      interpret: bool | None = None,
                      gamma5_in: bool = False, gamma5_out: bool = False,
                      psi_acc: jax.Array | None = None,
@@ -1059,7 +1164,7 @@ def dslash_oe_pallas(u_e: jax.Array, u_o: jax.Array, pp_e: jax.Array, *,
                      hop_twist: float = 0.0) -> jax.Array:
     """D_oe: even -> odd hopping block on packed half fields (see above)."""
     return _dslash_parity_pallas(
-        u_o, u_e, pp_e, parity=1, bz=bz, by=by, batch=batch, stream=stream,
+        u_o, u_e, pp_e, xh=xh, parity=1, bz=bz,
         interpret=interpret,
         gamma5_in=gamma5_in, gamma5_out=gamma5_out, psi_acc=psi_acc,
         acc_coeff=acc_coeff, hop_coeff=hop_coeff,

@@ -7,7 +7,8 @@ Full lattice:
 ``normal_op(...)``       — D^dag D psi (the CGNR operator; two kernel
                            launches, no standalone gamma5 application)
 
-Even-odd half lattice (parity-compressed X axis, see repro.core.lattice):
+Even-odd half lattice (packed half fields (T, 24, Z, Y*Xh), see
+repro.core.lattice; every entry point takes the compressed extent ``xh``):
 ``dslash_eo``/``dslash_oe`` — the parity-changing hopping blocks
 ``schur_op``                — D_hat = m psi - D_eo D_oe psi / m, with the
                               axpy folded into the second kernel's epilogue
@@ -15,7 +16,7 @@ Even-odd half lattice (parity-compressed X axis, see repro.core.lattice):
 ``schur_normal_op``         — D_hat^dag D_hat (four kernel launches total)
 
 Every entry point is **multi-RHS batched**: pass a spinor with a leading
-RHS-batch axis (N, T, Z, Y, 24, X[h]) and the same operator applies to all
+RHS-batch axis and the same operator applies to all
 N right-hand sides in the SAME kernel launches — the gauge field is read
 once per grid step and amortized across the batch, so ``schur_normal_op``
 stays exactly 4 launches (and ``normal_op`` exactly 2) independent of N.
@@ -33,6 +34,7 @@ import functools
 import jax
 
 from repro.core import phases
+from repro.core.lattice import HALF_S_AXIS
 from repro.core.operators import apply_igamma5_packed, schur_launch_coeffs
 from repro.core.wilson import apply_gamma5_packed, dslash_packed
 from repro.kernels.wilson_dslash.kernel import (dslash_eo_pallas,
@@ -44,6 +46,7 @@ from repro.kernels.wilson_dslash.ref import (dslash_eo_ref, dslash_oe_ref,
 
 _STATIC = ("mass", "twist", "bz", "interpret", "use_pallas")
 _STATIC_G5 = _STATIC + ("gamma5_in", "gamma5_out")
+_STATIC_XH = _STATIC + ("xh",)
 
 
 @phases.scoped(phases.DSLASH)
@@ -101,50 +104,55 @@ def normal_op(up: jax.Array, pp: jax.Array, mass: float, *,
 # Parity (even-odd) blocks and the Schur complement
 # ---------------------------------------------------------------------------
 
-_STATIC_EO = ("bz", "interpret", "use_pallas", "gamma5_in", "gamma5_out")
+_STATIC_EO = ("xh", "bz", "interpret", "use_pallas", "gamma5_in",
+              "gamma5_out")
 
 
 @phases.scoped(phases.HOP_EO)
 @functools.partial(jax.jit, static_argnames=_STATIC_EO)
-def dslash_eo(u_e: jax.Array, u_o: jax.Array, pp_o: jax.Array, *,
+def dslash_eo(u_e: jax.Array, u_o: jax.Array, pp_o: jax.Array, *, xh: int,
               bz: int | None = None, interpret: bool | None = None,
               use_pallas: bool = True, gamma5_in: bool = False,
               gamma5_out: bool = False) -> jax.Array:
     """D_eo: ODD half field in, EVEN half field out (hopping term only).
 
-    ``u_e``/``u_o`` are packed per-parity link fields (4, T, Z, Y, 18, Xh);
-    ``pp_o`` is a packed (T, Z, Y, 24, Xh) odd-parity spinor half field or
-    an (N, T, Z, Y, 24, Xh) RHS batch (gauge amortized across the batch).
+    ``u_e``/``u_o`` are packed per-parity link fields (4, T, 18, Z, Y*Xh);
+    ``pp_o`` is a packed (T, 24, Z, Y*Xh) odd-parity spinor half field or
+    an (N, T, 24, Z, Y*Xh) RHS batch (gauge amortized across the batch);
+    ``xh`` is the compressed X extent X/2.
     """
     if not use_pallas:
-        return dslash_eo_ref(u_e, u_o, pp_o, gamma5_in=gamma5_in,
+        return dslash_eo_ref(u_e, u_o, pp_o, xh=xh, gamma5_in=gamma5_in,
                              gamma5_out=gamma5_out)
-    return dslash_eo_pallas(u_e, u_o, pp_o, bz=bz, interpret=interpret,
-                            gamma5_in=gamma5_in, gamma5_out=gamma5_out)
+    return dslash_eo_pallas(u_e, u_o, pp_o, xh=xh, bz=bz,
+                            interpret=interpret, gamma5_in=gamma5_in,
+                            gamma5_out=gamma5_out)
 
 
 @phases.scoped(phases.HOP_OE)
 @functools.partial(jax.jit, static_argnames=_STATIC_EO)
-def dslash_oe(u_e: jax.Array, u_o: jax.Array, pp_e: jax.Array, *,
+def dslash_oe(u_e: jax.Array, u_o: jax.Array, pp_e: jax.Array, *, xh: int,
               bz: int | None = None, interpret: bool | None = None,
               use_pallas: bool = True, gamma5_in: bool = False,
               gamma5_out: bool = False) -> jax.Array:
     """D_oe: EVEN half field in, ODD half field out (hopping term only)."""
     if not use_pallas:
-        return dslash_oe_ref(u_e, u_o, pp_e, gamma5_in=gamma5_in,
+        return dslash_oe_ref(u_e, u_o, pp_e, xh=xh, gamma5_in=gamma5_in,
                              gamma5_out=gamma5_out)
-    return dslash_oe_pallas(u_e, u_o, pp_e, bz=bz, interpret=interpret,
-                            gamma5_in=gamma5_in, gamma5_out=gamma5_out)
+    return dslash_oe_pallas(u_e, u_o, pp_e, xh=xh, bz=bz,
+                            interpret=interpret, gamma5_in=gamma5_in,
+                            gamma5_out=gamma5_out)
 
 
-_STATIC_HOP = ("which", "bz", "interpret", "use_pallas", "gamma5_in",
+_STATIC_HOP = ("which", "xh", "bz", "interpret", "use_pallas", "gamma5_in",
                "gamma5_out", "acc_coeff", "hop_coeff", "acc_twist",
                "hop_twist")
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC_HOP)
 def hop_block(u_e: jax.Array, u_o: jax.Array, pp: jax.Array, *,
-              which: str, gamma5_in: bool = False, gamma5_out: bool = False,
+              which: str, xh: int, gamma5_in: bool = False,
+              gamma5_out: bool = False,
               psi_acc: jax.Array | None = None, acc_coeff: float = 0.0,
               hop_coeff: float = 1.0, acc_twist: float = 0.0,
               hop_twist: float = 0.0, bz: int | None = None,
@@ -170,30 +178,33 @@ def hop_block(u_e: jax.Array, u_o: jax.Array, pp: jax.Array, *,
                          f"got {which!r}")
     if not use_pallas:
         ref = dslash_eo_ref if which == "eo" else dslash_oe_ref
-        hop = ref(u_e, u_o, pp, gamma5_in=gamma5_in, gamma5_out=gamma5_out)
+        hop = ref(u_e, u_o, pp, xh=xh, gamma5_in=gamma5_in,
+                  gamma5_out=gamma5_out)
+        ig5 = lambda v: apply_igamma5_packed(v, axis=HALF_S_AXIS)
         out = hop if hop_coeff == 1.0 else hop_coeff * hop
         if hop_twist != 0.0:
-            out = out + hop_twist * apply_igamma5_packed(hop)
+            out = out + hop_twist * ig5(hop)
         if psi_acc is not None:
             acc = acc_coeff * psi_acc
             if acc_twist != 0.0:
-                acc = acc + acc_twist * apply_igamma5_packed(psi_acc)
+                acc = acc + acc_twist * ig5(psi_acc)
             out = acc + out
         return out.astype(pp.dtype)
     kern = dslash_eo_pallas if which == "eo" else dslash_oe_pallas
-    return kern(u_e, u_o, pp, bz=bz, interpret=interpret,
+    return kern(u_e, u_o, pp, xh=xh, bz=bz, interpret=interpret,
                 gamma5_in=gamma5_in, gamma5_out=gamma5_out,
                 psi_acc=psi_acc, acc_coeff=acc_coeff, hop_coeff=hop_coeff,
                 acc_twist=acc_twist, hop_twist=hop_twist)
 
 
-_STATIC_SCHUR = ("mass", "twist", "bz", "interpret", "use_pallas", "dagger")
+_STATIC_SCHUR = ("mass", "twist", "xh", "bz", "interpret", "use_pallas",
+                 "dagger")
 
 
 @phases.scoped(phases.SCHUR)
 @functools.partial(jax.jit, static_argnames=_STATIC_SCHUR)
 def schur_op(u_e: jax.Array, u_o: jax.Array, pp_e: jax.Array, mass: float, *,
-             twist: float = 0.0, bz: int | None = None,
+             xh: int, twist: float = 0.0, bz: int | None = None,
              interpret: bool | None = None, use_pallas: bool = True,
              dagger: bool = False) -> jax.Array:
     """Schur complement D_hat psi = S psi - D_eo S^-1 D_oe psi, where S is
@@ -210,41 +221,40 @@ def schur_op(u_e: jax.Array, u_o: jax.Array, pp_e: jax.Array, mass: float, *,
     hop epilogue and flipping the twist signs (S commutes with γ5).
     """
     if not use_pallas:
-        return schur_op_ref(u_e, u_o, pp_e, mass, twist=twist,
+        return schur_op_ref(u_e, u_o, pp_e, mass, xh=xh, twist=twist,
                             dagger=dagger)
     m = float(mass) + 4.0
+    kw = dict(xh=xh, bz=bz, interpret=interpret)
     if twist == 0.0:
-        tmp_o = dslash_oe_pallas(u_e, u_o, pp_e, bz=bz, interpret=interpret,
-                                 gamma5_in=dagger)
-        return dslash_eo_pallas(u_e, u_o, tmp_o, bz=bz, interpret=interpret,
-                                gamma5_out=dagger, psi_acc=pp_e,
-                                acc_coeff=m, hop_coeff=-1.0 / m)
+        tmp_o = dslash_oe_pallas(u_e, u_o, pp_e, gamma5_in=dagger, **kw)
+        return dslash_eo_pallas(u_e, u_o, tmp_o, gamma5_out=dagger,
+                                psi_acc=pp_e, acc_coeff=m, hop_coeff=-1.0 / m,
+                                **kw)
     # twisted site term: the two-launch split's sign algebra lives in
     # repro.core.operators.schur_launch_coeffs (shared with the sharded
     # halo path) — S(∓tw)^-1 folded into launch 1's hop epilogue,
     # S(±tw) into launch 2's accumulator.
     h1c, h1t, acc, acct = schur_launch_coeffs(m, twist, dagger)
-    tmp_o = dslash_oe_pallas(u_e, u_o, pp_e, bz=bz, interpret=interpret,
-                             gamma5_in=dagger, hop_coeff=h1c,
-                             hop_twist=h1t)
-    return dslash_eo_pallas(u_e, u_o, tmp_o, bz=bz, interpret=interpret,
-                            gamma5_out=dagger, psi_acc=pp_e, acc_coeff=acc,
-                            acc_twist=acct, hop_coeff=-1.0)
+    tmp_o = dslash_oe_pallas(u_e, u_o, pp_e, gamma5_in=dagger, hop_coeff=h1c,
+                             hop_twist=h1t, **kw)
+    return dslash_eo_pallas(u_e, u_o, tmp_o, gamma5_out=dagger, psi_acc=pp_e,
+                            acc_coeff=acc, acc_twist=acct, hop_coeff=-1.0,
+                            **kw)
 
 
-@functools.partial(jax.jit, static_argnames=_STATIC)
+@functools.partial(jax.jit, static_argnames=_STATIC_XH)
 def schur_dagger(u_e: jax.Array, u_o: jax.Array, pp_e: jax.Array,
-                 mass: float, *, twist: float = 0.0, bz: int | None = None,
-                 interpret: bool | None = None,
+                 mass: float, *, xh: int, twist: float = 0.0,
+                 bz: int | None = None, interpret: bool | None = None,
                  use_pallas: bool = True) -> jax.Array:
     """D_hat^dag = gamma5 D_hat(-twist) gamma5, folded into the kernels."""
-    return schur_op(u_e, u_o, pp_e, mass, twist=twist, bz=bz,
+    return schur_op(u_e, u_o, pp_e, mass, xh=xh, twist=twist, bz=bz,
                     interpret=interpret, use_pallas=use_pallas, dagger=True)
 
 
-@functools.partial(jax.jit, static_argnames=_STATIC)
+@functools.partial(jax.jit, static_argnames=_STATIC_XH)
 def schur_normal_op(u_e: jax.Array, u_o: jax.Array, pp_e: jax.Array,
-                    mass: float, *, twist: float = 0.0,
+                    mass: float, *, xh: int, twist: float = 0.0,
                     bz: int | None = None, interpret: bool | None = None,
                     use_pallas: bool = True) -> jax.Array:
     """A_hat = D_hat^dag D_hat — the even-sublattice CGNR operator.
@@ -255,8 +265,8 @@ def schur_normal_op(u_e: jax.Array, u_o: jax.Array, pp_e: jax.Array,
     exactly as often as its four hopping stencils demand.
     """
     if not use_pallas:
-        return schur_normal_op_ref(u_e, u_o, pp_e, mass, twist=twist)
-    w = schur_op(u_e, u_o, pp_e, mass, twist=twist, bz=bz,
+        return schur_normal_op_ref(u_e, u_o, pp_e, mass, xh=xh, twist=twist)
+    w = schur_op(u_e, u_o, pp_e, mass, xh=xh, twist=twist, bz=bz,
                  interpret=interpret)
-    return schur_op(u_e, u_o, w, mass, twist=twist, bz=bz,
+    return schur_op(u_e, u_o, w, mass, xh=xh, twist=twist, bz=bz,
                     interpret=interpret, dagger=True)

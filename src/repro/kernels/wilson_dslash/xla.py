@@ -20,9 +20,10 @@ lowerings only; this path is accuracy-gated in tests instead.
 
 Layout contract is identical to the kernels: packed site fields
 (..., T, Z, Y, 24, X) with X innermost, packed gauge (4, T, Z, Y, 18, X);
-the parity entry point works on half fields whose X axis is
-parity-compressed by 2 and supports the full fused-epilogue surface
-(psi_acc/acc_coeff/hop_coeff/acc_twist/hop_twist), so `schur_normal_op`
+the parity entry point takes the kernels' packed half fields
+(..., T, 24, Z, Y*Xh), computes on the (..., T, Z, Y, 24, Xh) view (one
+transpose each way at its boundary) and supports the full fused-epilogue
+surface (psi_acc/acc_coeff/hop_coeff/acc_twist/hop_twist), so `schur_normal_op`
 lowers to 4 calls of this function with zero extra full-field passes —
 the launch accounting matches the Pallas path one-for-one.
 """
@@ -33,7 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.lattice import (GAUGE_G, NCOL, NDIRS, NSPIN, SPINOR_S,
-                                 exact_einsum)
+                                exact_einsum, half_lanes, half_rows)
 from repro.kernels.wilson_dslash.kernel import _TABLES
 
 # Whole-field axes (from the right): site fields unpack to
@@ -167,7 +168,7 @@ def dslash_xla(up: jax.Array, pp: jax.Array, mass: float, *,
 
 
 def dslash_parity_xla(u_out: jax.Array, u_nbr: jax.Array, pp: jax.Array, *,
-                      parity: int, gamma5_in: bool = False,
+                      xh: int, parity: int, gamma5_in: bool = False,
                       gamma5_out: bool = False,
                       psi_acc: jax.Array | None = None,
                       acc_coeff: float = 0.0, hop_coeff: float = 1.0,
@@ -177,9 +178,22 @@ def dslash_parity_xla(u_out: jax.Array, u_nbr: jax.Array, pp: jax.Array, *,
 
     Mirrors ``_dslash_parity_kernel`` on whole half fields: T/Z/Y
     neighbours are rolls, the parity-compressed X neighbour is a per-row
-    select between the field and its lane-rolled copy, with the row's
+    select between the field and its rolled copy, with the row's
     output-site offset s_out = (t + z + y + parity) mod 2.
     """
+    rows = lambda a: half_rows(a, xh)
+    out = _dslash_parity_rows(
+        rows(u_out), rows(u_nbr), rows(pp), parity=parity,
+        gamma5_in=gamma5_in, gamma5_out=gamma5_out,
+        psi_acc=None if psi_acc is None else rows(psi_acc),
+        acc_coeff=acc_coeff, hop_coeff=hop_coeff, acc_twist=acc_twist,
+        hop_twist=hop_twist)
+    return half_lanes(out)
+
+
+def _dslash_parity_rows(u_out, u_nbr, pp, *, parity, gamma5_in, gamma5_out,
+                        psi_acc, acc_coeff, hop_coeff, acc_twist, hop_twist):
+    """:func:`dslash_parity_xla` on the (..., T, Z, Y, C, Xh) views."""
     nd, t, z, y, g, x = u_out.shape
     assert nd == NDIRS and g == GAUGE_G and u_nbr.shape == u_out.shape
     assert pp.shape[-5:] == (t, z, y, SPINOR_S, x)

@@ -17,14 +17,17 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import (LatticeShape, pack_gauge, pack_spinor, random_gauge,
+from repro.core import (LatticeShape, pack_gauge, pack_half_gauge,
+                        pack_half_spinor, pack_spinor, random_gauge,
                         random_spinor, split_eo, split_eo_gauge)
 from repro.kernels import autotune, dispatch
 from repro.kernels.dispatch import (DEFAULT_TILE, TileConfig, cache_key,
                                     parse_tile, pick_tile, save_tuning_cache)
 from repro.kernels.wilson_dslash.kernel import (VMEM_BUDGET_BYTES,
                                                 _divisors, _fit_tile,
-                                                _pick_by, _pick_bz,
+                                                _pick_by,
+                                                _pick_bz,
+                                                _resolve_parity_tile,
                                                 dslash_pallas,
                                                 launch_vmem_bytes)
 from repro.kernels.wilson_dslash.ops import schur_normal_op
@@ -96,20 +99,48 @@ def test_pick_by_error_lists_legal_values():
 
 
 def test_fit_tile_shrinks_to_vmem_and_names_the_shape():
-    """The default tile: the historical default where it fits, a
-    smaller block where it does not (24³×48 parity fields), and an error
+    """The full-lattice default tile: the historical default where it
+    fits, a smaller block where it does not (24⁴ fields), and an error
     naming the plane when not even bz=1, by=1 fits."""
     f32 = jnp.float32
-    parity = dict(gauge_centers=2, spinor_blocks=4)
-    assert _fit_tile(2, 2, 4, None, f32, f32, **parity) == (2, 2, "block")
-    bz, by, batch = _fit_tile(24, 24, 12, None, f32, f32, **parity)
+    assert _fit_tile(2, 2, 4, None, f32, f32) == (2, 2, "block")
+    bz, by, batch = _fit_tile(24, 24, 24, None, f32, f32)
     assert (batch, 24 % bz, 24 % by) == ("block", 0, 0)
-    assert launch_vmem_bytes(bz, by, 24, 12, 1, f32, f32,
-                             **parity) <= VMEM_BUDGET_BYTES
-    assert launch_vmem_bytes(_pick_bz(24, None), 24, 24, 12, 1, f32, f32,
-                             **parity) > VMEM_BUDGET_BYTES
+    assert launch_vmem_bytes(bz, by, 24, 24, 1, f32,
+                             f32) <= VMEM_BUDGET_BYTES
+    assert launch_vmem_bytes(_pick_bz(24, None), 24, 24, 24, 1, f32,
+                             f32) > VMEM_BUDGET_BYTES
     with pytest.raises(ValueError, match=r"\(Z, Y, X\) = \(24, 24, 4096\)"):
-        _fit_tile(24, 24, 4096, None, f32, f32, **parity)
+        _fit_tile(24, 24, 4096, None, f32, f32)
+
+
+def test_parity_fit_tile_holds_whole_planes_and_names_the_shape():
+    """Parity launches hold whole t-planes: the body chunk is one sublane
+    tile (bz = 8) at 24³×48, at 16³×32 and at 32³×64, the whole Z where
+    no sublane tile divides it (Z = 20) or the storage is bf16, an
+    explicit chunk outside those is refused, and the error names the
+    plane when nothing fits."""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    fit = lambda dims, dt: _resolve_parity_tile(None, dims, dt,
+                                                gauge_dtype=dt, has_acc=True)
+    for dims in ((48, 24, 24, 12), (32, 16, 16, 8), (64, 32, 32, 16)):
+        assert fit(dims, f32) == 8
+    assert fit((48, 24, 24, 12), bf16) == 24
+    assert fit((32, 16, 16, 8), bf16) == 16
+    assert fit((8, 20, 20, 10), f32) == 20
+    assert fit((2, 4, 4, 4), f32) == 4
+    assert _resolve_parity_tile(1, (48, 24, 24, 12), f32, gauge_dtype=f32,
+                                has_acc=False) == 1
+    with pytest.raises(ValueError, match=r"bz=4 is not a parity chunk.*"
+                                         r"\[1, 8, 24\]"):
+        _resolve_parity_tile(4, (48, 24, 24, 12), f32, gauge_dtype=f32,
+                             has_acc=False)
+    with pytest.raises(ValueError, match=r"bz=1 is not a parity chunk.*"
+                                         r"\[16\]"):
+        _resolve_parity_tile(1, (32, 16, 16, 8), bf16, gauge_dtype=bf16,
+                             has_acc=False)
+    with pytest.raises(ValueError, match=r"\(Z, Y\*Xh\) = \(64, 8192\)"):
+        fit((8, 64, 128, 64), f32)
 
 
 def test_tile_config_validates():
@@ -255,21 +286,49 @@ def test_sweep_smoke_and_autotune_roundtrip(tmp_path, monkeypatch):
 
 
 def test_schur_four_launches_under_forced_tile(monkeypatch):
-    """schur_normal_op stays exactly 4 kernel launches (and bitwise
-    stable) under a non-default forced tile."""
+    """A forced tile steers only the full-lattice kernel: schur_normal_op
+    stays exactly 4 kernel launches, and bitwise the same, under it."""
     lat = LatticeShape(2, 2, 2, 4)
     ku, kp = jax.random.split(jax.random.PRNGKey(5))
     u_e, u_o = split_eo_gauge(random_gauge(ku, lat))
     p_e, _ = split_eo(random_spinor(kp, lat))
-    upe, upo, ppe = pack_gauge(u_e), pack_gauge(u_o), pack_spinor(p_e)
-    ref = np.asarray(schur_normal_op(upe, upo, ppe, MASS))
+    upe, upo = pack_half_gauge(u_e), pack_half_gauge(u_o)
+    ppe = pack_half_spinor(p_e)
+    xh = lat.x // 2
+    ref = np.asarray(schur_normal_op(upe, upo, ppe, MASS, xh=xh))
 
-    monkeypatch.setenv("REPRO_DSLASH_TILE", "bz=2,stream=db")
+    monkeypatch.setenv("REPRO_DSLASH_TILE", "bz=1,stream=db")
+    jax.clear_caches()          # trace again, under the override
     jaxpr = jax.make_jaxpr(
-        lambda a, b, c: schur_normal_op(a, b, c, MASS))(upe, upo, ppe)
+        lambda a, b, c: schur_normal_op(a, b, c, MASS, xh=xh))(upe, upo,
+                                                              ppe)
     assert len(pallas_call_eqns(jaxpr)) == 4
-    assert np.array_equal(np.asarray(schur_normal_op(upe, upo, ppe, MASS)),
-                          ref)
+    assert np.array_equal(
+        np.asarray(schur_normal_op(upe, upo, ppe, MASS, xh=xh)), ref)
+
+
+def test_schur_four_launches_under_forced_chunk():
+    """schur_normal_op stays exactly 4 kernel launches under a forced
+    one-row chunk, and agrees with the default chunk to f32 rounding.
+    The chunk's arithmetic is the same site by site, but the CPU
+    interpreter compiles (bz, lanes) arrays of another shape into other
+    fusions, which round a multiply-add differently (about 1e-7 of the
+    field's scale), so the comparison is not bitwise."""
+    lat = LatticeShape(2, 2, 2, 4)
+    ku, kp = jax.random.split(jax.random.PRNGKey(5))
+    u_e, u_o = split_eo_gauge(random_gauge(ku, lat))
+    p_e, _ = split_eo(random_spinor(kp, lat))
+    upe, upo = pack_half_gauge(u_e), pack_half_gauge(u_o)
+    ppe = pack_half_spinor(p_e)
+    xh = lat.x // 2
+    ref = np.asarray(schur_normal_op(upe, upo, ppe, MASS, xh=xh))
+    jaxpr = jax.make_jaxpr(
+        lambda a, b, c: schur_normal_op(a, b, c, MASS, xh=xh, bz=1))(
+            upe, upo, ppe)
+    assert len(pallas_call_eqns(jaxpr)) == 4
+    out = np.asarray(schur_normal_op(upe, upo, ppe, MASS, xh=xh, bz=1))
+    np.testing.assert_allclose(out, ref, rtol=0,
+                               atol=1e-6 * np.abs(ref).max())
 
 
 def test_checked_in_cache_is_well_formed():

@@ -107,28 +107,29 @@ out["eo_sharded"] = {
 
 # the Pallas parity kernels as the sharded bulk stencil: one halo matvec
 # against the global single-device operator
+xh = lat.x // 2
 u_e, u_o = split_eo_gauge(U)
-upe, upo = pack_gauge(u_e), pack_gauge(u_o)
-pe = pack_spinor(split_eo(psi)[0])
-psi_spec2, gauge_spec2, sharded2 = dist.lattice_specs(mesh)
+upe, upo = pack_half_gauge(u_e), pack_half_gauge(u_o)
+pe = pack_half_spinor(split_eo(psi)[0])
+psi_spec2, gauge_spec2, sharded2 = dist.half_lattice_specs(mesh)
 upe_d = jax.device_put(upe, NamedSharding(mesh, gauge_spec2))
 upo_d = jax.device_put(upo, NamedSharding(mesh, gauge_spec2))
 pe_d = jax.device_put(pe, NamedSharding(mesh, psi_spec2))
 fk = jax.jit(jax.shard_map(
     lambda ue, uo, p: dist.schur_normal_op_halo(ue, uo, p, m, sharded2,
-                                                use_pallas=True),
+                                                xh=xh, use_pallas=True),
     mesh=mesh, in_specs=(gauge_spec2, gauge_spec2, psi_spec2),
     out_specs=psi_spec2, check_vma=False))
-ref = wops.schur_normal_op(upe, upo, pe, m, use_pallas=False)
+ref = wops.schur_normal_op(upe, upo, pe, m, xh=xh, use_pallas=False)
 out["eo_halo_pallas_err"] = float(jnp.max(jnp.abs(
     fk(upe_d, upo_d, pe_d) - ref)))
 
 # the fused-reduction contract: the pipelined sharded CGNR's while body
 # holds EXACTLY ONE psum, for the whole batch (jaxpr-level, no execution)
 bspec = P(None, *psi_spec2)
-pbe = pack_spinor(jax.vmap(split_eo)(bb)[0])
-pbo = pack_spinor(jax.vmap(split_eo)(bb)[1])
-kkw = dict(sharded=sharded2, use_pallas=False)
+pbe = pack_half_spinor(jax.vmap(split_eo)(bb)[0])
+pbo = pack_half_spinor(jax.vmap(split_eo)(bb)[1])
+kkw = dict(sharded=sharded2, xh=xh, use_pallas=False)
 pdot, pnorm2 = dist.make_psum_dots(mesh, batched=True)
 fused = dist.make_fused_psum_dots(mesh, batched=True)
 

@@ -6,8 +6,9 @@ import numpy as np
 import pytest
 
 from repro.core import (LatticeShape, complex_to_real_pair, pack_gauge,
-                        pack_spinor, random_gauge, random_spinor,
-                        real_pair_to_complex, split_eo, split_eo_gauge)
+                        pack_half_gauge, pack_half_spinor, pack_spinor,
+                        random_gauge, random_spinor, real_pair_to_complex,
+                        split_eo, split_eo_gauge)
 from repro.kernels.cg_fused import (cg_pallas, cg_update, cg_update_ref,
                                     cg_xpay, cg_xpay_ref)
 from repro.kernels.wilson_dslash import dslash as dslash_k
@@ -91,31 +92,34 @@ def test_dslash_kernel_dagger_hermiticity(fields):
 # ---------------------------------------------------------------------------
 
 
+def _eo_packed(lat, key):
+    """Packed per-parity links + packed halves of a random spinor."""
+    ku, kp = jax.random.split(jax.random.fold_in(key, lat.volume))
+    u_e, u_o = split_eo_gauge(random_gauge(ku, lat))
+    p_e, p_o = split_eo(random_spinor(kp, lat))
+    return (pack_half_gauge(u_e), pack_half_gauge(u_o),
+            pack_half_spinor(p_e), pack_half_spinor(p_o))
+
+
 @pytest.fixture(scope="module")
 def eo_fields():
     """Packed per-parity fields + packed halves of a random spinor."""
     key = jax.random.PRNGKey(23)
-    out = {}
-    for lat in EO_SHAPES:
-        ku, kp = jax.random.split(jax.random.fold_in(key, lat.volume))
-        u = random_gauge(ku, lat)
-        psi = random_spinor(kp, lat)
-        u_e, u_o = split_eo_gauge(u)
-        p_e, p_o = split_eo(psi)
-        out[lat.dims] = (pack_gauge(u_e), pack_gauge(u_o),
-                         pack_spinor(p_e), pack_spinor(p_o))
-    return out
+    return {lat.dims: _eo_packed(lat, key) for lat in EO_SHAPES}
 
 
 @pytest.mark.parametrize("lat", EO_SHAPES, ids=str)
 def test_parity_kernels_match_core(eo_fields, lat):
     """D_eo / D_oe Pallas kernels match the core oracles to <= 1e-5."""
     upe, upo, ppe, ppo = eo_fields[lat.dims]
-    np.testing.assert_allclose(np.asarray(eo_k(upe, upo, ppo)),
-                               np.asarray(dslash_eo_ref(upe, upo, ppo)),
+    xh = lat.x // 2
+    np.testing.assert_allclose(np.asarray(eo_k(upe, upo, ppo, xh=xh)),
+                               np.asarray(dslash_eo_ref(upe, upo, ppo,
+                                                        xh=xh)),
                                atol=1e-5)
-    np.testing.assert_allclose(np.asarray(oe_k(upe, upo, ppe)),
-                               np.asarray(dslash_oe_ref(upe, upo, ppe)),
+    np.testing.assert_allclose(np.asarray(oe_k(upe, upo, ppe, xh=xh)),
+                               np.asarray(dslash_oe_ref(upe, upo, ppe,
+                                                        xh=xh)),
                                atol=1e-5)
 
 
@@ -125,16 +129,17 @@ def test_schur_kernel_matches_core(eo_fields, lat, dagger):
     """The 2-launch Schur kernel (γ5 + axpy folded) matches the oracle,
     including the γ5-folded dagger path."""
     upe, upo, ppe, _ = eo_fields[lat.dims]
-    out = schur_k(upe, upo, ppe, EO_MASS, dagger=dagger)
-    ref = schur_op_ref(upe, upo, ppe, EO_MASS, dagger=dagger)
+    xh = lat.x // 2
+    out = schur_k(upe, upo, ppe, EO_MASS, xh=xh, dagger=dagger)
+    ref = schur_op_ref(upe, upo, ppe, EO_MASS, xh=xh, dagger=dagger)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
 
 def test_schur_normal_op_matches_core(eo_fields):
     lat = EO_SHAPES[0]
     upe, upo, ppe, _ = eo_fields[lat.dims]
-    out = schur_nk(upe, upo, ppe, EO_MASS)
-    ref = schur_normal_op_ref(upe, upo, ppe, EO_MASS)
+    out = schur_nk(upe, upo, ppe, EO_MASS, xh=lat.x // 2)
+    ref = schur_normal_op_ref(upe, upo, ppe, EO_MASS, xh=lat.x // 2)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
 
@@ -142,8 +147,10 @@ def test_parity_gamma5_flags_match_ref(eo_fields):
     """The folded gamma5_in/gamma5_out flags equal explicit γ5 wrapping."""
     lat = EO_SHAPES[0]
     upe, upo, ppe, _ = eo_fields[lat.dims]
-    out = oe_k(upe, upo, ppe, gamma5_in=True, gamma5_out=True)
-    ref = dslash_oe_ref(upe, upo, ppe, gamma5_in=True, gamma5_out=True)
+    xh = lat.x // 2
+    out = oe_k(upe, upo, ppe, xh=xh, gamma5_in=True, gamma5_out=True)
+    ref = dslash_oe_ref(upe, upo, ppe, xh=xh, gamma5_in=True,
+                        gamma5_out=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
 
@@ -159,8 +166,9 @@ def test_gamma5_folding_zero_extra_passes(fields, eo_fields):
     assert full_field_passes(jx, pp.size) == []
 
     upe, upo, ppe, _ = eo_fields[EO_SHAPES[0].dims]
+    xh = EO_SHAPES[0].x // 2
     jx = jax.make_jaxpr(
-        lambda a, b, v: schur_nk(a, b, v, EO_MASS, interpret=True))(
+        lambda a, b, v: schur_nk(a, b, v, EO_MASS, xh=xh, interpret=True))(
             upe, upo, ppe)
     assert len(pallas_call_eqns(jx)) == 4
     assert full_field_passes(jx, ppe.size) == []
@@ -261,9 +269,9 @@ def batched_eo_fields():
     u_e, u_o = split_eo_gauge(u)
     halves = [split_eo(random_spinor(jax.random.fold_in(kp, i), lat))
               for i in range(3)]
-    ppe = jnp.stack([pack_spinor(h[0]) for h in halves])
-    ppo = jnp.stack([pack_spinor(h[1]) for h in halves])
-    return pack_gauge(u_e), pack_gauge(u_o), ppe, ppo
+    ppe = jnp.stack([pack_half_spinor(h[0]) for h in halves])
+    ppo = jnp.stack([pack_half_spinor(h[1]) for h in halves])
+    return pack_half_gauge(u_e), pack_half_gauge(u_o), ppe, ppo
 
 
 def test_batched_parity_kernels_bitwise_match_looped(batched_eo_fields):
@@ -271,12 +279,13 @@ def test_batched_parity_kernels_bitwise_match_looped(batched_eo_fields):
     fetch) produce bitwise the same halves as N single-RHS launches."""
     upe, upo, ppe, ppo = batched_eo_fields
     n = ppe.shape[0]
-    out = eo_k(upe, upo, ppo)
-    ref = jnp.stack([eo_k(upe, upo, ppo[i]) for i in range(n)])
+    xh = EO_SHAPES[0].x // 2
+    out = eo_k(upe, upo, ppo, xh=xh)
+    ref = jnp.stack([eo_k(upe, upo, ppo[i], xh=xh) for i in range(n)])
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
-    out = oe_k(upe, upo, ppe, gamma5_in=True, gamma5_out=True)
-    ref = jnp.stack([oe_k(upe, upo, ppe[i], gamma5_in=True, gamma5_out=True)
-                     for i in range(n)])
+    out = oe_k(upe, upo, ppe, xh=xh, gamma5_in=True, gamma5_out=True)
+    ref = jnp.stack([oe_k(upe, upo, ppe[i], xh=xh, gamma5_in=True,
+                          gamma5_out=True) for i in range(n)])
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
@@ -284,11 +293,12 @@ def test_batched_parity_kernels_bitwise_match_looped(batched_eo_fields):
 def test_batched_schur_matches_looped_and_ref(batched_eo_fields, dagger):
     upe, upo, ppe, _ = batched_eo_fields
     n = ppe.shape[0]
-    out = schur_k(upe, upo, ppe, EO_MASS, dagger=dagger)
-    looped = jnp.stack([schur_k(upe, upo, ppe[i], EO_MASS, dagger=dagger)
-                        for i in range(n)])
+    xh = EO_SHAPES[0].x // 2
+    out = schur_k(upe, upo, ppe, EO_MASS, xh=xh, dagger=dagger)
+    looped = jnp.stack([schur_k(upe, upo, ppe[i], EO_MASS, xh=xh,
+                                dagger=dagger) for i in range(n)])
     np.testing.assert_array_equal(np.asarray(out), np.asarray(looped))
-    ref = schur_op_ref(upe, upo, ppe, EO_MASS, dagger=dagger)
+    ref = schur_op_ref(upe, upo, ppe, EO_MASS, xh=xh, dagger=dagger)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
 
@@ -314,8 +324,9 @@ def test_batched_schur_normal_op_launch_count_independent_of_n(
     zero standalone full-field γ5/axpy/cast passes, whatever N is."""
     upe, upo, ppe, _ = batched_eo_fields
     batch = jnp.concatenate([ppe] * 2)[:n_rhs]
+    xh = EO_SHAPES[0].x // 2
     jx = jax.make_jaxpr(
-        lambda a, b, v: schur_nk(a, b, v, EO_MASS, interpret=True))(
+        lambda a, b, v: schur_nk(a, b, v, EO_MASS, xh=xh, interpret=True))(
             upe, upo, batch)
     assert len(pallas_call_eqns(jx)) == 4
     assert full_field_passes(jx, batch.size) == []       # batched fields

@@ -18,9 +18,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import (LatticeShape, SolverPlan, pack_gauge, pack_spinor,
-                        random_gauge, random_spinor, solve_plan, split_eo,
-                        split_eo_gauge)
+from repro.core import (LatticeShape, SolverPlan, pack_half_gauge,
+                        pack_half_spinor, pack_spinor, random_gauge,
+                        random_spinor, solve_plan, split_eo, split_eo_gauge)
 from repro.core.lattice import field_dot
 from repro.core.operators import (LatticeOperator, SiteTerm,
                                   apply_igamma5_packed, dslash_dagger_g,
@@ -30,6 +30,7 @@ from repro.core.operators import (LatticeOperator, SiteTerm,
 from repro.testing import full_field_passes, pallas_call_eqns
 
 LAT = LatticeShape(2, 4, 4, 4)  # small: interpret-mode trace cost
+XH = LAT.x // 2
 MASS = 0.1
 MU = 0.3
 TOL = 1e-6
@@ -47,7 +48,7 @@ def eo_packed(problem):
     u, b = problem
     u_e, u_o = split_eo_gauge(u)
     p_e, _ = split_eo(b)
-    return pack_gauge(u_e), pack_gauge(u_o), pack_spinor(p_e)
+    return pack_half_gauge(u_e), pack_half_gauge(u_o), pack_half_spinor(p_e)
 
 
 def _rel_res_tm(u, x, b):
@@ -148,14 +149,14 @@ def test_site_term_apply_solve_round_trip(problem):
     v = split_eo(b)[0]
     np.testing.assert_allclose(np.asarray(site.solve(site.apply(v))),
                                np.asarray(v), atol=1e-6)
-    # packed real layout (dispatch on dtype) round-trips too
-    p = pack_spinor(v)
+    # packed half-field layout (dispatch on dtype) round-trips too
+    p = pack_half_spinor(v)
     np.testing.assert_allclose(np.asarray(site.solve(site.apply(p))),
                                np.asarray(p), atol=1e-6)
     # packed apply agrees with the natural-layout definition
     nat = site.apply(v)
     np.testing.assert_allclose(np.asarray(site.apply(p)),
-                               np.asarray(pack_spinor(nat)), atol=1e-6)
+                               np.asarray(pack_half_spinor(nat)), atol=1e-6)
     # dagger flips the twist; inverse is analytic
     assert site.dag.twist == -MU and site.inv.twist == pytest.approx(
         -MU / ((MASS + 4.0) ** 2 + MU ** 2))
@@ -210,9 +211,9 @@ def test_twisted_schur_kernel_matches_reference(eo_packed, dagger):
     from repro.kernels.wilson_dslash import ops as wops
     from repro.kernels.wilson_dslash.ref import schur_op_ref
     upe, upo, ppe = eo_packed
-    out = wops.schur_op(upe, upo, ppe, MASS, twist=MU, dagger=dagger,
+    out = wops.schur_op(upe, upo, ppe, MASS, xh=XH, twist=MU, dagger=dagger,
                         interpret=True)
-    ref = schur_op_ref(upe, upo, ppe, MASS, twist=MU, dagger=dagger)
+    ref = schur_op_ref(upe, upo, ppe, MASS, xh=XH, twist=MU, dagger=dagger)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
 
@@ -228,9 +229,11 @@ def test_twisted_batched_schur_matches_looped(eo_packed):
     key = jax.random.PRNGKey(11)
     batch = jnp.stack([ppe * (i + 1.0) for i in range(3)]) \
         + jax.random.normal(key, (3,) + ppe.shape, jnp.float32)
-    out = wops.schur_op(upe, upo, batch, MASS, twist=MU, interpret=True)
-    looped = jnp.stack([wops.schur_op(upe, upo, batch[i], MASS, twist=MU,
-                                      interpret=True) for i in range(3)])
+    out = wops.schur_op(upe, upo, batch, MASS, xh=XH, twist=MU,
+                        interpret=True)
+    looped = jnp.stack([wops.schur_op(upe, upo, batch[i], MASS, xh=XH,
+                                      twist=MU, interpret=True)
+                        for i in range(3)])
     np.testing.assert_allclose(np.asarray(out), np.asarray(looped),
                                atol=2e-6)
 
@@ -246,7 +249,8 @@ def test_schur_normal_op_is_4_launches_for_both_families(eo_packed, twist,
     upe, upo, ppe = eo_packed
     v = ppe if n_rhs is None else jnp.stack([ppe] * n_rhs)
     jx = jax.make_jaxpr(
-        lambda a, b, w: wops.schur_normal_op(a, b, w, MASS, twist=twist,
+        lambda a, b, w: wops.schur_normal_op(a, b, w, MASS, xh=XH,
+                                             twist=twist,
                                              interpret=True))(upe, upo, v)
     assert len(pallas_call_eqns(jx)) == 4
     assert full_field_passes(jx, v.size) == []

@@ -1,4 +1,4 @@
-"""Main-path kernels compile for a TPU v5e chip at 24³×48.
+"""Main-path kernels compile for a TPU v5e chip at 24³×48 (and 16³×32).
 
 The TPU compiler compiles for a chip that is described, not attached, so
 these tests need no accelerator: they lower the Pallas kernels with the
@@ -10,6 +10,7 @@ library, and every test worker imports this file.
 """
 
 import contextlib
+import functools
 import os
 import re
 
@@ -27,6 +28,7 @@ from repro.kernels.wilson_dslash.ops import schur_normal_op
 
 T, Z, Y, X = 48, 24, 24, 24   # ETMC A40.24-sized lattice
 XH = X // 2
+SERVED = (32, 16, 16, 16, 8)  # tm-16c32 at the served ladder's top rung
 HBM_BYTES = 16 * 10**9
 
 
@@ -74,10 +76,15 @@ def mosaic():
         yield
 
 
-def _half_fields(one_chip):
-    spec = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+def _half_fields(one_chip, dims=(T, Z, Y, X), nrhs=None, dtype=jnp.float32):
+    """Packed per-parity links (4, T, 18, Z, Y*Xh) and a spinor half field
+    (T, 24, Z, Y*Xh), with a leading RHS batch when ``nrhs`` is given."""
+    t, z, y, x = dims
+    spec = lambda shape: jax.ShapeDtypeStruct(shape, dtype,
                                               sharding=one_chip)
-    return spec((4, T, Z, Y, 18, XH)), spec((T, Z, Y, 24, XH))
+    batch = (nrhs,) if nrhs else ()
+    return (spec((4, t, 18, z, y * x // 2)),
+            spec(batch + (t, 24, z, y * x // 2)))
 
 
 def _compile(fn, *args):
@@ -93,30 +100,86 @@ def test_parity_kernel_default_tile_compiles(mosaic, one_chip, kernel):
     u, p = _half_fields(one_chip)
     fn = {"eo": dslash_kernel.dslash_eo_pallas,
           "oe": dslash_kernel.dslash_oe_pallas}[kernel]
-    _compile(fn, u, u, p)
+    _compile(functools.partial(fn, xh=XH), u, u, p)
+
+
+@pytest.mark.parametrize("kernel", ["eo", "oe"])
+def test_parity_kernel_served_batch_compiles(mosaic, one_chip, kernel):
+    """16³×32 with N = 8 right-hand sides (a full lane row, Y*Xh = 128),
+    the batch on the grid."""
+    *dims, nrhs = SERVED
+    u, p = _half_fields(one_chip, dims, nrhs)
+    fn = {"eo": dslash_kernel.dslash_eo_pallas,
+          "oe": dslash_kernel.dslash_oe_pallas}[kernel]
+    _compile(functools.partial(fn, xh=dims[3] // 2), u, u, p)
+
+
+# (dims, nrhs, dtype, bz, the chunk the launch must take)
+CHUNK_CASES = {
+    "bf16-24c48": ((T, Z, Y, X), None, jnp.bfloat16, None, Z),
+    "bf16-16c32-n8": (SERVED[:4], SERVED[4], jnp.bfloat16, None, 16),
+    "f32-z20": ((8, 20, 20, 20), None, jnp.float32, None, 20),
+    "f32-z12": ((8, 12, 12, 12), None, jnp.float32, None, 12),
+    "f32-24c48-whole-z": ((T, Z, Y, X), None, jnp.float32, Z, Z),
+}
+
+
+@pytest.mark.parametrize("case", list(CHUNK_CASES))
+def test_parity_kernel_chunks_compile(mosaic, one_chip, case):
+    """Every kind of chunk the parity kernels may take compiles: bf16
+    storage (the mixed-precision solve's inner operator, whole Z), Z
+    extents with no whole sublane tile (Z = 20, and Z = 12 with 72 lanes),
+    the whole Z of an f32 field.  One row is `test_unit_blocks_compile`."""
+    dims, nrhs, dtype, bz, want = CHUNK_CASES[case]
+    u, p = _half_fields(one_chip, dims, nrhs, dtype)
+    xh = dims[3] // 2
+    assert dslash_kernel._resolve_parity_tile(
+        bz, (dims[0], dims[1], dims[2], xh), dtype, gauge_dtype=dtype,
+        has_acc=True) == want
+    _compile(lambda a, b, c: dslash_kernel.dslash_eo_pallas(
+        a, b, c, xh=xh, bz=bz, psi_acc=c, acc_coeff=4.1, hop_coeff=-1.0),
+        u, u, p)
 
 
 def test_parity_kernel_fused_twisted_epilogue_compiles(mosaic, one_chip):
     """The Schur launch with the accumulator operand and both twists."""
     u, p = _half_fields(one_chip)
     _compile(lambda a, b, c: dslash_kernel.dslash_eo_pallas(
-        a, b, c, psi_acc=c, acc_coeff=4.1, acc_twist=0.25,
+        a, b, c, xh=XH, psi_acc=c, acc_coeff=4.1, acc_twist=0.25,
         hop_coeff=-1.0, hop_twist=0.1), u, u, p)
 
 
 def test_unit_blocks_compile(mosaic, one_chip):
-    """bz=1 and by=1: the z- and y-shifts of a one-plane block are the
-    boundary plane itself (no zero-width slice)."""
+    """bz=1: the z-shift of a one-row chunk is the edge row itself (no
+    zero-width slice)."""
     u, p = _half_fields(one_chip)
     _compile(lambda a, b, c: dslash_kernel.dslash_eo_pallas(
-        a, b, c, bz=1, by=1), u, u, p)
+        a, b, c, xh=XH, bz=1), u, u, p)
 
 
 @pytest.mark.parametrize("twist", [0.0, 0.25])
 def test_schur_normal_op_compiles(mosaic, one_chip, twist):
     u, p = _half_fields(one_chip)
     compiled = _compile(lambda a, b, c: schur_normal_op(
-        a, b, c, 0.1, twist=twist), u, u, p)
+        a, b, c, 0.1, xh=XH, twist=twist), u, u, p)
+    assert compiled.as_text().count("tpu_custom_call") >= 4
+
+
+def test_mixed_precision_schur_normal_op_compiles(mosaic, one_chip):
+    """The inner operator of the mixed-precision solve: bf16 half fields
+    at 24³×48."""
+    u, p = _half_fields(one_chip, dtype=jnp.bfloat16)
+    compiled = _compile(lambda a, b, c: schur_normal_op(
+        a, b, c, 0.1, xh=XH), u, u, p)
+    assert compiled.as_text().count("tpu_custom_call") >= 4
+
+
+def test_served_schur_normal_op_compiles(mosaic, one_chip):
+    """The served twisted operator at 16³×32 on an N = 8 batch."""
+    *dims, nrhs = SERVED
+    u, p = _half_fields(one_chip, dims, nrhs)
+    compiled = _compile(lambda a, b, c: schur_normal_op(
+        a, b, c, 0.1, xh=dims[3] // 2, twist=0.25), u, u, p)
     assert compiled.as_text().count("tpu_custom_call") >= 4
 
 
